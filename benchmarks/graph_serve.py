@@ -13,7 +13,7 @@ Emits wall time per REQUEST plus the deterministic batching counters
 the serve layer guarantees -- requests/wave, padded-slot waste
 (node/edge), and bucket compiles (one set of compiled programs per
 (stage, node_cap, edge_cap) bucket) -- which ``run.py --check``
-guards against the committed ``BENCH_smoke.json`` in both CI lanes.
+guards against the committed ``BENCH_smoke.json`` in CI.
 Wall-derived numbers (the speedup) are printed as comments only: the
 counters in ``derived`` must be deterministic at a given scale.
 """

@@ -133,6 +133,10 @@ def main(argv=None) -> None:
     if args.smoke:  # must land before benchmarks.common reads the env
         os.environ["REPRO_BENCH_SCALE"] = SMOKE_SCALE
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    print(f"# compile cache: {use_compile_cache()}", flush=True)
+
     if args.trace:
         from repro.obs import trace as obs_trace
 

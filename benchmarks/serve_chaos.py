@@ -8,7 +8,7 @@ counters -- completed/failed/retried/quarantined/degraded/bisections/
 wave_runs. Everything in ``derived`` is deterministic: the plan is
 seeded, the stream is seeded, and the containment pipeline
 (``serve/waves.py``) is sequential -- so ``run.py --check`` guards the
-counters against ``BENCH_smoke.json`` in both CI lanes exactly like
+counters against ``BENCH_smoke.json`` in CI exactly like
 the packing counters. A drift here means the containment semantics
 changed: retry budgets, bisection probe order, or degradation
 re-packing.

@@ -1,71 +1,33 @@
-"""JAX API-drift shims: one import site for everything that moved.
+"""JAX API shims: one import site for the mesh and shard_map APIs.
 
-The repo targets current JAX but must also run on the 0.4.x line (the
-pinned CI environment). Three API families drifted between those:
+The repo targets the one installed JAX release (pinned in
+``pyproject.toml``). Every mesh and every shard_map in the repo routes
+through this module, so an API that moves in a later release is absorbed
+HERE, never inline at a call site:
 
-* ``jax.sharding.AxisType`` + ``jax.make_mesh(..., axis_types=...)`` --
-  the explicit-sharding mesh flags do not exist before jax 0.5; meshes
-  built here behave as ``Auto`` on old releases (which is all this repo
-  ever asks for).
-* ``jax.shard_map`` -- lived at ``jax.experimental.shard_map.shard_map``
-  until ~0.6, and its replication-check kwarg was renamed
-  ``check_rep`` -> ``check_vma`` when it was promoted.
-* ``jax.tree`` utilities and friends occasionally move; anything else
-  that drifts gets its shim added HERE, never inline at a call site.
+* ``jax.make_mesh`` defaults its axes to ``Explicit`` sharding; the
+  engines here are written for ``Auto`` axes, so ``make_mesh`` asks for
+  them unless the caller says otherwise.
+* ``jax.shard_map`` with its ``check_vma`` replication check.
 
-Every mesh and every shard_map in the repo routes through this module so
-the same code runs on jax 0.4.x through current. ``Mesh`` is re-exported
-from here for the same reason: call sites write ``from repro.compat
-import Mesh`` so this stays the one direct ``jax.sharding`` import site
-(enforced by the compat-shim lint pass, docs/lint.md).
+``Mesh`` is re-exported from here for the same reason: call sites write
+``from repro.compat import Mesh`` so this stays the one direct
+``jax.sharding`` import site (enforced by the compat-shim lint pass,
+docs/lint.md).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable, Sequence
 
 import numpy as np
 
 import jax
-from jax.sharding import Mesh  # noqa: F401  (re-exported, see docstring)
-
-# --------------------------------------------------------------------------
-# AxisType (explicit-sharding flags, jax >= 0.5)
-# --------------------------------------------------------------------------
-
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-if HAS_AXIS_TYPES:
-    AxisType = jax.sharding.AxisType
-else:
-
-    class AxisType:  # type: ignore[no-redef]
-        """Stand-in for jax.sharding.AxisType on pre-0.5 releases.
-
-        Old JAX has no explicit-sharding mode; every mesh axis behaves as
-        ``Auto``, so the sentinels only need to exist and be distinct.
-        """
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+from jax.sharding import AxisType, Mesh  # noqa: F401  (re-exported)
 
 
 def auto_axis_types(ndim: int):
     """(AxisType.Auto,) * ndim -- the only mode this repo uses."""
     return (AxisType.Auto,) * ndim
-
-
-# --------------------------------------------------------------------------
-# Mesh construction
-# --------------------------------------------------------------------------
-
-_MAKE_MESH = getattr(jax, "make_mesh", None)
-_MAKE_MESH_KW = (
-    frozenset(inspect.signature(_MAKE_MESH).parameters)
-    if _MAKE_MESH is not None
-    else frozenset()
-)
 
 
 def make_mesh(
@@ -75,52 +37,21 @@ def make_mesh(
     axis_types: tuple | None = None,
     devices: Sequence[Any] | None = None,
 ) -> Mesh:
-    """``jax.make_mesh`` that works on jax 0.4.x through current.
+    """``jax.make_mesh`` with ``Auto`` axes unless ``axis_types`` says
+    otherwise.
 
-    * ``axis_types`` is forwarded when the installed ``jax.make_mesh``
-      accepts it and silently dropped otherwise (pre-0.5 JAX is always
-      implicitly Auto, so dropping it preserves semantics).
-    * ``devices`` pins the mesh to an explicit device list IN THAT ORDER
-      (jax.make_mesh may permute devices for ICI topology; tests and
-      sub-meshes need determinism), falling back to direct ``Mesh``
-      construction.
+    ``devices`` pins the mesh to an explicit device list IN THAT ORDER
+    (jax.make_mesh may permute devices for ICI topology; tests and
+    sub-meshes need determinism).
     """
     shape = tuple(int(s) for s in axis_shapes)
     names = tuple(axis_names)
+    if axis_types is None:
+        axis_types = auto_axis_types(len(shape))
     if devices is not None:
-        n = int(np.prod(shape))
-        dev = np.asarray(list(devices)[:n]).reshape(shape)
-        # Forward axis_types only on AxisType-era jax: 0.4.x Mesh also
-        # has an axis_types kwarg but with different (dict-shaped,
-        # experimental) semantics, and old jax is implicitly Auto anyway.
-        if axis_types is not None and HAS_AXIS_TYPES:
-            return Mesh(dev, names, axis_types=axis_types)
-        return Mesh(dev, names)
-    if _MAKE_MESH is not None:
-        kw = {}
-        if axis_types is not None and "axis_types" in _MAKE_MESH_KW:
-            kw["axis_types"] = axis_types
-        return _MAKE_MESH(shape, names, **kw)
-    dev = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return Mesh(dev, names)
-
-
-# --------------------------------------------------------------------------
-# shard_map
-# --------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _SHARD_MAP = jax.shard_map
-else:  # pre-promotion location
-    from jax.experimental.shard_map import shard_map as _SHARD_MAP
-
-_SHARD_MAP_KW = frozenset(inspect.signature(_SHARD_MAP).parameters)
-# check_rep (old) was renamed check_vma (new); pick whichever exists.
-_REP_KW = (
-    "check_vma"
-    if "check_vma" in _SHARD_MAP_KW
-    else ("check_rep" if "check_rep" in _SHARD_MAP_KW else None)
-)
+        dev = np.asarray(list(devices)[: int(np.prod(shape))]).reshape(shape)
+        return Mesh(dev, names, axis_types=axis_types)
+    return jax.make_mesh(shape, names, axis_types=axis_types)
 
 
 def shard_map(
@@ -131,31 +62,14 @@ def shard_map(
     out_specs,
     check_vma: bool = True,
 ) -> Callable:
-    """``jax.shard_map`` with the current calling convention on any jax.
-
-    ``check_vma=False`` maps to ``check_rep=False`` on old releases (the
-    replication checker predates varying-manual-axes but guards the same
-    thing: collectives whose replication the tracer cannot prove).
-    """
-    kw = {_REP_KW: check_vma} if _REP_KW is not None else {}
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-# --------------------------------------------------------------------------
-# Tracer detection (host-driven engines need concrete inputs)
-# --------------------------------------------------------------------------
-
-_TRACER_T = getattr(getattr(jax, "core", None), "Tracer", None)
+    """``jax.shard_map`` (``check_vma=False`` turns off the replication
+    check for collectives whose replication the tracer cannot prove)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )
 
 
 def is_tracer(x: Any) -> bool:
-    """True when ``x`` is a JAX tracer (i.e. we are inside a jit trace).
-
-    If a future release moves ``jax.core.Tracer``, fall back to the
-    class name (every tracer class is a ``*Tracer``) -- erring toward
-    tracer, because mis-dispatching a tracer into a host-driven engine
-    crashes while the traceable fallback path merely runs unfused.
-    """
-    if _TRACER_T is not None:
-        return isinstance(x, _TRACER_T)
-    return "Tracer" in type(x).__name__
+    """True when ``x`` is a JAX tracer (i.e. we are inside a jit trace)."""
+    return isinstance(x, jax.core.Tracer)

@@ -121,8 +121,9 @@ def connected_components(
       both frontier engines (per-device in the sharded one).
     * ``hook_impl=`` -- ``"xla"`` (default), ``"auto"``, ``"pallas"``,
       ``"pallas_interpret"``: the SV2/SV3 hook-phase implementation
-      (``kernels/edge_hook``); dense, frontier, and sharded-frontier
-      engines (shard-local in the latter).
+      (``kernels/edge_hook``; ``"auto"`` takes the XLA phases, since
+      the chip's compiler refuses the kernel); dense, frontier, and
+      sharded-frontier engines (shard-local in the latter).
     * ``exchange=`` -- ``"dense"`` or ``"sparse"``: the cross-device
       label exchange; sharded engines only. Defaults: ``"dense"`` on
       the dense sharded engine, ``"sparse"`` on the sharded frontier
@@ -273,10 +274,12 @@ def list_rank(succ, num_splitters=None, *, mesh=None, **kwargs):
 
     * ``num_splitters=`` (int, default: ``min(4096,
       max_splitters_for_linear_work(n))``) -- RS1 splitter count.
-    * ``kernel_impl=`` -- ``"auto"`` (default), ``"xla"``, ``"pallas"``,
+    * ``kernel_impl=`` -- ``"xla"``, ``"auto"``, ``"pallas"``,
       ``"pallas_interpret"``: routes the RS4/RS5 phases through the
-      Pallas kernels; honoured by BOTH engines ("auto" compiles them on
-      real TPUs and keeps plain XLA elsewhere).
+      Pallas kernels; honoured by BOTH engines. Defaults: ``"xla"`` on
+      the single-device engine, ``"auto"`` on the sharded one; "auto"
+      keeps plain XLA on every backend, since the chip's compiler
+      refuses both kernels (``repro.kernels``).
     * ``pack_mode=`` -- ``"aos"`` (default), ``"soa"``, ``"word64"``:
       single-device walk-state packing (Table 2); when given without a
       mesh it pins the single-device engine on any machine, combining

@@ -318,10 +318,10 @@ def random_splitter_rank(
     """Rank a linked list with Reid-Miller's random splitter algorithm.
 
     ``kernel_impl`` routes the RS4/RS5 phases through the Pallas
-    kernels: "auto" compiles them on a real TPU backend and keeps plain
-    XLA elsewhere; "pallas"/"pallas_interpret" force the kernel path
-    (interpreted off-TPU). Unknown strings raise (they used to fall
-    through to the XLA path silently).
+    kernels: "auto" keeps plain XLA (the chip's compiler refuses both
+    kernels, see ``repro.kernels``); "pallas"/"pallas_interpret" force
+    the kernel path (interpreted off-TPU). Unknown strings raise (they
+    used to fall through to the XLA path silently).
 
     If ``max_steps`` cuts the lockstep walk off before every lane
     reaches its splitter, the ranks would be wrong -- host calls raise
@@ -330,12 +330,11 @@ def random_splitter_rank(
     """
     from repro.compat import is_tracer
     from repro.core.components import ConvergenceError
-    from repro.kernels import on_tpu
 
     check_choice("pack_mode", pack_mode, PACK_MODES)
     check_choice("kernel_impl", kernel_impl, KERNEL_IMPLS)
     if kernel_impl == "auto":
-        kernel_impl = "pallas" if on_tpu() else "xla"
+        kernel_impl = "xla"  # the chip's compiler refuses the kernels
     succ = jnp.asarray(succ)
     n = int(succ.shape[0])
     if splitters is None:

@@ -22,8 +22,9 @@ Three operator groups (see docs/operators.md for the full contract):
   scatter-determinism discipline. ``ADD`` (PageRank mass) is
   commutative but float-add is not associative, so its determinism
   contract is weaker: bit-stable for a fixed edge-slot order on a
-  backend with deterministic scatter accumulation (CPU/TPU XLA), which
-  is exactly what the serial oracle mirrors via ``np.add.at``.
+  backend with deterministic scatter accumulation in ``np.add.at``'s
+  order (XLA on the CPU), which is exactly what the serial oracle
+  mirrors. On a TPU the scores stay within float32 rounding of it.
 * **filter** -- the frontier machinery: ``next_pow2`` size buckets,
   ``compact_frontier`` / ``compact_weighted`` (gather the masked live
   edges into a fixed-size buffer padded with inert self-loops), and

@@ -16,8 +16,9 @@ order (the teleport term is the scatter's *base*, not a post-add --
 that keeps XLA from contracting a multiply-add into an FMA, which
 would unpin the serial oracle). ``core.serial.serial_pagerank``
 mirrors the exact op sequence with ``np.add.at``, whose accumulation
-order matches the XLA scatter-add on the CPU/TPU backends, so engine
-scores are bit-identical to the oracle, iteration for iteration.
+order matches the XLA scatter-add on the CPU backend, so engine
+scores are bit-identical to the oracle, iteration for iteration (on a
+TPU they agree within float32 rounding, not bit for bit).
 Per-node ``teleport`` vectors make the serve path's disjoint-union
 packing decompose: a request's slice of the packed union sees exactly
 its solo teleport mass, pad nodes carry zero and stay zero. Dangling

@@ -164,8 +164,9 @@ def serial_pagerank(
     count: the exact float32 op sequence -- separately-rounded
     multiplies, teleport as the scatter BASE, ``np.add.at``
     accumulation in edge-slot order (which matches the XLA scatter-add
-    on the CPU/TPU backends) -- so scores pin both device engines
-    bit-for-bit, iteration for iteration. ``weights=None`` means unit
+    on the CPU backend) -- so scores pin both device engines
+    bit-for-bit, iteration for iteration, on the CPU. On a TPU they
+    agree within float32 rounding, not bit for bit. ``weights=None`` means unit
     weights; dangling mass leaks exactly like the engines'."""
     u, v, w = _sssp_arcs(edges, weights)
     dmp = np.float32(damping)

@@ -40,8 +40,8 @@ with one associative label exchange.
   back out over node blocks, so the output materialises already
   edge-partitioned (out_spec P(axis)).  ``kernel_impl`` routes RS4/RS5
   through the Pallas kernels (``kernels/pointer_jump``,
-  ``kernels/splitter_aggregate``) inside each shard -- "auto" compiles
-  them on real TPUs and keeps plain XLA elsewhere.
+  ``kernels/splitter_aggregate``) inside each shard -- "auto" keeps
+  plain XLA, since the chip's compiler refuses both kernels.
 
 Both functions are bit-exact against their single-device counterparts
 (asserted by ``tests/multidev_scripts.py sharded_cc / sharded_rank``),
@@ -852,16 +852,15 @@ def sharded_random_splitter_rank(
 
     ``kernel_impl`` routes the RS4/RS5 phases through the Pallas kernels
     (``kernels/pointer_jump``, ``kernels/splitter_aggregate``) inside
-    each device's shard: "auto" compiles them on a real TPU backend and
-    keeps the plain-XLA phases elsewhere; "pallas"/"pallas_interpret"
-    force the kernel path (interpreted off-TPU). All routes are
-    bit-exact -- the phases are integer-exact in any implementation.
+    each device's shard: "auto" keeps the plain-XLA phases (the chip's
+    compiler refuses both kernels, see ``repro.kernels``);
+    "pallas"/"pallas_interpret" force the kernel path (interpreted
+    off-TPU). All routes are bit-exact -- the phases are integer-exact
+    in any implementation.
     """
-    from repro.kernels import on_tpu
-
     check_choice("kernel_impl", kernel_impl, KERNEL_IMPLS)
     if kernel_impl == "auto":
-        kernel_impl = "pallas" if on_tpu() else "xla"
+        kernel_impl = "xla"  # the chip's compiler refuses the kernels
     mesh = mesh if mesh is not None else graph_mesh(axis=axis)
     axis = _resolve_axis(mesh, axis)
     nd = mesh.shape[axis]

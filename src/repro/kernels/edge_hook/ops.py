@@ -1,17 +1,17 @@
-"""Public wrapper: fused SV hook with automatic path choice.
+"""Public wrapper: fused SV hook (Pallas kernel) or the unfused XLA phases.
 
-``impl="auto"`` fuses on a real TPU whenever the label + stamp arrays
-fit VMEM (same small/large split as ``kernels/pointer_jump``) and falls
-back to the unfused XLA phases elsewhere; ``"pallas_interpret"`` runs
-the kernel body as plain JAX ops for CPU validation.
+``impl="auto"`` runs the XLA phases everywhere: the chip's compiler
+refuses the kernel's 1-D in-VMEM gathers (``kernels/__init__``).
+``"pallas"`` compiles the kernel on a TPU backend (interpreted
+elsewhere); ``"pallas_interpret"`` runs the kernel body as plain JAX
+ops for CPU validation.
 
 The kernel is **shard-local by construction**: it reads only the edge
 arrays it is handed and the replicated label/stamp state, so the
 sharded frontier engine (``distributed/graph``, ``hook_impl=``) runs it
 unchanged inside ``shard_map`` -- each device fuses the hook phases
 over its own compacted edge bucket, and the per-round label exchanges
-see identical arrays either way. The VMEM budget is per device, so
-``VMEM_NODE_LIMIT`` needs no mesh scaling.
+see identical arrays either way.
 """
 from __future__ import annotations
 
@@ -20,13 +20,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret, on_tpu
+from repro.kernels import default_interpret
 from repro.kernels.edge_hook.edge_hook import edge_hook_pallas
 from repro.kernels.edge_hook.ref import edge_hook_ref
-
-# Two int32 arrays (labels + stamps) resident plus streaming tiles; half
-# the pointer_jump budget keeps headroom for the edge tiles.
-VMEM_NODE_LIMIT = 1 << 19
 
 
 @partial(jax.jit, static_argnames=("mode", "impl", "block_e"))
@@ -47,11 +43,8 @@ def edge_hook(
     ``labels_prev`` (the pre-shortcut labels) is required for mode="sv2"
     (the stagnant-tree check); mode="sv3" ignores it.
     """
-    n = labels.shape[0]
     prev = labels_prev if labels_prev is not None else labels
-    if impl == "auto":
-        impl = "pallas" if (on_tpu() and n <= VMEM_NODE_LIMIT) else "xla"
-    if impl == "xla":
+    if impl in ("auto", "xla"):
         return edge_hook_ref(a, b, labels, prev, stamps, s, mode=mode)
     if impl not in ("pallas", "pallas_interpret"):
         raise ValueError(f"unknown impl {impl!r}")

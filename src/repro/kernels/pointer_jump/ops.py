@@ -1,4 +1,4 @@
-"""Public wrapper: VMEM pointer jumping with automatic path choice."""
+"""Public wrapper: VMEM pointer jumping (Pallas kernel) or its XLA path."""
 from __future__ import annotations
 
 import math
@@ -6,14 +6,9 @@ from functools import partial
 
 import jax
 
-from repro.kernels import default_interpret, on_tpu
+from repro.kernels import default_interpret
 from repro.kernels.pointer_jump.pointer_jump import pointer_jump_pallas
 from repro.kernels.pointer_jump.ref import pointer_jump_ref
-
-# Above this many nodes the list no longer fits VMEM comfortably and the
-# multi-"kernel" XLA path (HBM round trips per step) is used instead --
-# the same small/large split as the paper's single- vs multi-kernel Wylie.
-VMEM_NODE_LIMIT = 1 << 20
 
 
 @partial(jax.jit, static_argnames=("iters", "impl"))
@@ -27,7 +22,7 @@ def pointer_jump(
     p = nxt.shape[0]
     iters = iters if iters is not None else max(1, math.ceil(math.log2(max(p, 2))))
     if impl == "auto":
-        impl = "pallas" if (on_tpu() and p <= VMEM_NODE_LIMIT) else "xla"
+        impl = "xla"  # the chip's compiler refuses the kernel (kernels/__init__)
     if impl == "pallas":
         return pointer_jump_pallas(nxt, w, iters=iters, interpret=default_interpret())
     if impl == "pallas_interpret":

@@ -11,7 +11,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret, on_tpu
+from repro.kernels import default_interpret
 from repro.kernels.segment_sum.ref import segment_sum_sorted_ref
 from repro.kernels.segment_sum.segment_sum import segment_sum_sorted_pallas
 
@@ -40,9 +40,9 @@ def segment_sum_sorted(
             default (all blocks) is safe but slow -- callers with degree
             bounds should pass ceil(max_in_degree_per_block / block_e) + 1.
     """
-    if impl == "auto":
-        impl = "pallas" if on_tpu() else "xla"
-    if impl == "xla":
+    # "auto" is the XLA path: the chip's compiler refuses the kernel at
+    # the default block_e (kernels/__init__).
+    if impl in ("auto", "xla"):
         return segment_sum_sorted_ref(data, seg_ids, num_segments)
 
     m, d = data.shape

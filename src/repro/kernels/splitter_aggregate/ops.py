@@ -6,7 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret, on_tpu
+from repro.kernels import default_interpret
 from repro.kernels.splitter_aggregate.ref import splitter_aggregate_ref
 from repro.kernels.splitter_aggregate.splitter_aggregate import (
     splitter_aggregate_pallas,
@@ -21,9 +21,9 @@ def splitter_aggregate(
     impl: str = "auto",
     block_n: int = 2048,
 ) -> jax.Array:
-    if impl == "auto":
-        impl = "pallas" if on_tpu() else "xla"
-    if impl == "xla":
+    # "auto" is the XLA path: the chip's compiler refuses the kernel
+    # (kernels/__init__).
+    if impl in ("auto", "xla"):
         return splitter_aggregate_ref(packed, sprank)
     n = packed.shape[0]
     pad = (-n) % block_n

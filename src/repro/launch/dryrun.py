@@ -1,5 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# Fake CPU devices only, here and in every child it starts (they inherit
+# the environment): the dry run must never take a chip from another
+# process.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run driver.
 
@@ -14,8 +18,8 @@ Run everything (per-cell subprocesses, results appended to a JSON file):
     PYTHONPATH=src python -m repro.launch.dryrun --all \
         --out results/dryrun.json
 
-The XLA_FLAGS line above MUST stay the first statement: jax locks the device
-count at first import.
+The XLA_FLAGS and JAX_PLATFORMS lines above MUST stay the first statements:
+jax locks the platform and device count at first import.
 """
 import argparse
 import json

@@ -3,8 +3,8 @@
 Functions, not module-level constants: importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before any jax import).
 
-All construction routes through ``repro.compat.make_mesh`` so the same
-builders work on jax 0.4.x (no AxisType / axis_types kwarg) and current.
+All construction routes through ``repro.compat.make_mesh`` (``Auto``
+axes, the one mesh-API import site).
 """
 from __future__ import annotations
 

@@ -17,8 +17,8 @@ from repro.core.serial import (
 
 
 def test_axis_type_sentinels_exist():
-    assert compat.AxisType.Auto is not None
-    assert len(compat.auto_axis_types(3)) == 3
+    assert compat.AxisType.Auto != compat.AxisType.Explicit
+    assert compat.auto_axis_types(3) == (compat.AxisType.Auto,) * 3
 
 
 def test_make_mesh_accepts_and_survives_axis_types():
@@ -27,6 +27,11 @@ def test_make_mesh_accepts_and_survives_axis_types():
     )
     assert mesh.axis_names == ("data", "model")
     assert mesh.shape == {"data": 1, "model": 1}
+    # jax.make_mesh alone would give Explicit axes; the shim defaults
+    # to the Auto axes every engine here is written for.
+    for m in (mesh, compat.make_mesh((1,), ("x",)),
+              compat.make_mesh((1,), ("x",), devices=jax.devices()[:1])):
+        assert set(m.axis_types) == {compat.AxisType.Auto}
 
 
 def test_make_mesh_explicit_devices_keeps_order():

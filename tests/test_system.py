@@ -1,8 +1,15 @@
 """End-to-end behaviour: the paper's pipeline (generate -> rank -> verify),
-LM training convergence on the smoke config, and serving round trips."""
+LM training convergence on the smoke config, serving round trips, and the
+on-chip entry point's refusal to run without a TPU."""
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_arch
 from repro.core import num_components, random_splitter_rank, shiloach_vishkin
@@ -84,3 +91,57 @@ def test_serve_after_train_roundtrip(tmp_path):
         logits, cache = serve_step(restored, cfg, cache, tok, jnp.int32(i))
         tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
     assert bool(jnp.isfinite(logits).all())
+
+
+_ROOT = Path(__file__).resolve().parents[1]
+
+
+def _run(args, **env):
+    base = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    return subprocess.run(
+        [sys.executable, *args], capture_output=True, text=True, timeout=120,
+        cwd=_ROOT, env={**base, "JAX_PLATFORMS": "cpu",
+                        "PYTHONPATH": str(_ROOT / "src"), **env},
+    )
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """The on-chip check never falls back to the CPU: it names the
+    missing TPU and prints no result line."""
+    r = _run([str(_ROOT / "chip_smoke.py")])
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert '"ok"' not in r.stdout
+
+
+_CACHE_PROBE = """
+import sys
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import use_compile_cache
+print(use_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+if sys.argv[1] == "compile":
+    jax.block_until_ready(jax.jit(lambda x: x + 1)(jnp.ones(3)))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "default"])
+def test_compile_cache_location(tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` wins (JAX reads it itself); without
+    it the cache sits at the fixed in-checkout path. The default case
+    compiles nothing, so it writes nothing into the checkout."""
+    from repro.launch.compile_cache import CACHE_DIR
+
+    env = {"JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+           "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0"}
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = want = str(tmp_path / "cache")
+    else:
+        want = str(CACHE_DIR)
+    r = _run(["-c", _CACHE_PROBE, "compile" if from_env else "-"], **env)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split() == [want, want]
+    assert CACHE_DIR == _ROOT / ".jax_cache"
+    if from_env:
+        assert any((tmp_path / "cache").iterdir())

@@ -1,13 +1,13 @@
 """compat-shim (RL003): JAX drift-prone APIs route through repro.compat.
 
-PR 1's invariant: ``repro/compat.py`` is the ONE import site for every
-JAX API that has moved across the supported release range
-(``shard_map``'s home and kwarg names, ``make_mesh`` / ``AxisType``,
-and ``Mesh`` as the shim's re-export anchor). Any direct import or
-attribute use of those names outside compat.py reintroduces the drift
-the shim exists to absorb -- the pinned CI lane (jax 0.4.x) and the
-latest-jax lane only both stay green because call sites cannot bypass
-the shim.
+Invariant: ``repro/compat.py`` is the ONE import site for the JAX APIs
+that have moved between releases and may move again (``shard_map``'s
+home and kwarg names, ``make_mesh`` / ``AxisType`` and the ``Auto`` axis
+default, and ``Mesh`` as the shim's re-export anchor). Any direct import
+or attribute use of those names outside compat.py bypasses the shim: a
+bare ``jax.make_mesh`` gives ``Explicit`` axes the engines are not
+written for, and the next API move would have to be chased through
+every call site.
 
 Flagged outside ``src/repro/compat.py``:
 
@@ -83,6 +83,6 @@ class CompatShimPass(LintPass):
             module,
             node,
             f"`{name}` used directly; import `{short}` from "
-            "`repro.compat` -- the single API-drift shim site (PR 1 "
-            "invariant; keeps jax 0.4.x and latest-jax lanes green)",
+            "`repro.compat` -- the single API-drift shim site (Auto "
+            "mesh axes; one place to absorb the next API move)",
         )

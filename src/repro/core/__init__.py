@@ -38,6 +38,7 @@ from repro.core.pram import (
     partitioned_view,
     lockstep_walk,
 )
+from repro.obs import trace
 
 
 # Engine-specific tuning knobs: naming one pins the dispatch to that
@@ -145,6 +146,18 @@ def connected_components(
     engine/kwarg combination is bit-exact in labels, round counts, and
     recorded hook forests against every other.
     """
+    # The root span of one library call: every engine span, the dedup,
+    # the uploads and the host syncs of the call sit beneath it.
+    with trace.span("cc.call", n=num_nodes) as sp:
+        if trace.enabled():
+            sp.tag(m=len(src))
+        return _dispatch_cc(src, dst, num_nodes, max_rounds, mesh, engine,
+                            kwargs, sp)
+
+
+def _dispatch_cc(src, dst, num_nodes, max_rounds, mesh, engine, kwargs, sp):
+    """``connected_components``' engine choice and call, under its
+    ``cc.call`` span ``sp``."""
     import jax
 
     from repro.compat import is_tracer
@@ -195,6 +208,7 @@ def connected_components(
             auto_k = _auto_sample_rounds(src, num_nodes)
             if auto_k:
                 kwargs["sample_rounds"] = auto_k
+    sp.tag(engine=engine)
     if engine == "frontier":
         if sharded_kw:
             raise ValueError(

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace
+
 Array = jax.Array
 
 
@@ -392,7 +394,8 @@ def _maybe_dedup(src, dst, dedup: bool):
     )
     if not dedup or not host:
         return src, dst
-    return dedup_edges(src, dst)
+    with trace.span("cc.dedup"):
+        return dedup_edges(src, dst)
 
 
 @partial(
@@ -438,7 +441,6 @@ def shiloach_vishkin(
     explicit too-small ``max_rounds`` or a broken round invariant.
     """
     from repro.compat import is_tracer
-    from repro.obs import trace
 
     n = num_nodes
     check_choice("hook_impl", hook_impl, HOOK_IMPLS)
@@ -460,6 +462,7 @@ def shiloach_vishkin(
     if not is_tracer(converged):
         # Intentional terminal sync: the sentinel must be read before
         # wrong labels can escape (docstring above).
+        trace.count("host_sync")
         if not bool(converged):  # repro-lint: disable=host-sync
             raise ConvergenceError(
                 f"shiloach_vishkin hit max_rounds={bound} before the "

@@ -196,10 +196,11 @@ def frontier_shiloach_vishkin(
     n = num_nodes
     check_choice("hook_impl", hook_impl, HOOK_IMPLS)
     src, dst = _maybe_dedup(src, dst, dedup)
-    src = jnp.asarray(src, jnp.int32).ravel()
-    dst = jnp.asarray(dst, jnp.int32).ravel()
-    a = jnp.concatenate([src, dst])
-    b = jnp.concatenate([dst, src])
+    with trace.span("cc.upload"):
+        src = jnp.asarray(src, jnp.int32).ravel()
+        dst = jnp.asarray(dst, jnp.int32).ravel()
+        a = jnp.concatenate([src, dst])
+        b = jnp.concatenate([dst, src])
     m2 = int(a.shape[0])
 
     bound = (max_rounds if max_rounds is not None else sv_round_bound(n))
@@ -212,35 +213,38 @@ def frontier_shiloach_vishkin(
                           sample_rounds=sample_rounds)
 
     if sample_rounds > 0 and m2 > 0:
-        sample_sp = trace.span("cc.frontier.sample", k=sample_rounds)
-        sample_sp.__enter__()
-        rng = np.random.default_rng(seed)
-        perm = jnp.asarray(rng.permutation(m2).astype(np.int32))
-        samples = _build_samples(a, b, perm, n=n, k=sample_rounds)
-        stats.edges_touched += m2  # the sampling pass streams all edges once
-        for t in range(sample_rounds):
-            D, Q, aux, s, _changed = _sample_round(
-                samples[:, t], D, Q, s, aux, n=n, record_hooks=record_hooks
-            )
-            stats.edges_touched += 2 * n  # SV2 + SV3 over the n sampled edges
-        if with_stats:  # O(n) scatter + host sync: only when asked for
-            # repro-lint: disable=host-sync  (opt-in stats readback)
-            stats.largest_component_frac = float(
-                _largest_component_frac(D, n=n)
-            )
-        # Compact straight away: drops ALL edges internal to the giant
-        # (and to every other component the pre-pass already resolved).
-        live_mask = D[a] != D[b]
-        # The level-synchronous sync (paper sec. 4): the host must see the
-        # live count to pick the next power-of-two bucket.
-        live = int(jnp.sum(live_mask.astype(jnp.int32)))  # repro-lint: disable=host-sync
-        stats.live_after_sample = live
-        stats.edges_touched += m2  # full-list live scan (pre-pass rounds
-        # walked only the sampled edges, so this mask needs its own pass)
-        size = bucket_size(live, min_bucket=min_bucket, cap=m2)
-        a, b = compact_frontier(a, b, live_mask, size=size)
-        m2_level = size
-        sample_sp.tag(live=live).__exit__(None, None, None)
+        with trace.span("cc.frontier.sample", k=sample_rounds) as sample_sp:
+            with trace.span("cc.frontier.sample.permute"):
+                rng = np.random.default_rng(seed)
+                perm = jnp.asarray(rng.permutation(m2).astype(np.int32))
+            samples = _build_samples(a, b, perm, n=n, k=sample_rounds)
+            stats.edges_touched += m2  # the sampling pass streams all edges
+            for t in range(sample_rounds):
+                D, Q, aux, s, _changed = _sample_round(
+                    samples[:, t], D, Q, s, aux, n=n,
+                    record_hooks=record_hooks,
+                )
+                stats.edges_touched += 2 * n  # SV2 + SV3, n sampled edges
+            if with_stats:  # O(n) scatter + host sync: only when asked for
+                trace.count("host_sync")
+                # repro-lint: disable=host-sync  (opt-in stats readback)
+                stats.largest_component_frac = float(
+                    _largest_component_frac(D, n=n)
+                )
+            # Compact straight away: drops ALL edges internal to the giant
+            # (and to every other component the pre-pass already resolved).
+            live_mask = D[a] != D[b]
+            # The level-synchronous sync (paper sec. 4): the host must see
+            # the live count to pick the next power-of-two bucket.
+            trace.count("host_sync")
+            live = int(jnp.sum(live_mask.astype(jnp.int32)))  # repro-lint: disable=host-sync
+            stats.live_after_sample = live
+            stats.edges_touched += m2  # full-list live scan (pre-pass rounds
+            # walked only the sampled edges, so this mask needs its own pass)
+            size = bucket_size(live, min_bucket=min_bucket, cap=m2)
+            a, b = compact_frontier(a, b, live_mask, size=size)
+            m2_level = size
+            sample_sp.tag(live=live)
     else:
         m2_level = m2
 
@@ -270,17 +274,22 @@ def frontier_shiloach_vishkin(
                 # the host reads one round count / convergence flag /
                 # live count per LEVEL to drive the shrink ladder -- the
                 # paper's level-synchronous design.
+                trace.count("host_sync")
                 level_rounds = int(rounds)  # repro-lint: disable=host-sync
                 stats.edges_touched += passes * level_rounds * bucket
                 stats.levels.append((bucket, level_rounds))
+                trace.count("host_sync")
                 converged = not bool(changed)  # repro-lint: disable=host-sync
                 sp.tag(rounds=level_rounds, converged=converged)
-            over = not converged and int(s) > bound  # repro-lint: disable=host-sync
-            return converged, over
+            if converged:
+                return True, False
+            trace.count("host_sync")
+            return False, int(s) > bound  # repro-lint: disable=host-sync
 
         def live_edges():
             # Shrink: the masked frontier fits the next power-of-two
             # bucket.
+            trace.count("host_sync")
             return int(jnp.sum(fmask.astype(jnp.int32)))  # repro-lint: disable=host-sync
 
         def charge_shrink(new_size):
@@ -308,8 +317,10 @@ def frontier_shiloach_vishkin(
             live_count=live_edges, compact=shrink, on_shrink=charge_shrink,
             on_nonconverged=bound_hit,
         )
-        D = sv_compress(D, n)
+        with trace.span("cc.compress"):
+            D = sv_compress(D, n)
         # Terminal readback: the loop above already synced on s per level.
+        trace.count("host_sync")
         rounds_total = int(s) - 1  # repro-lint: disable=host-sync
         run_sp.tag(rounds=rounds_total, levels=len(stats.levels))
     stats.rounds = rounds_total

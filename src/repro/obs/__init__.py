@@ -10,7 +10,7 @@ per-phase table. Full model: ``docs/observability.md``.
 """
 from repro.obs import metrics, trace
 from repro.obs.metrics import Registry, publish_stats
-from repro.obs.trace import PROFILE_MODES, TRACE_MODES, Tracer
+from repro.obs.trace import TRACE_MODES, Tracer
 
 __all__ = [
     "trace",
@@ -19,5 +19,4 @@ __all__ = [
     "Registry",
     "publish_stats",
     "TRACE_MODES",
-    "PROFILE_MODES",
 ]

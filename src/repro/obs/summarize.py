@@ -6,8 +6,11 @@
 Reads the ``{"traceEvents": [...]}`` JSON written by
 ``repro.obs.trace.export_chrome`` (a bare event list also works),
 aggregates the complete events (``ph="X"``) by span name, and prints
-count / total / mean / max wall time per phase, widest total first --
-the quick answer to "where did the time go" without opening Perfetto.
+count / total / self / mean / max wall time per phase and the phase's
+summed counters (``args.counts``), widest total first -- the quick
+answer to "where did the time go" without opening Perfetto. A span's
+self time is its duration less that of its children, the spans whose
+``args.parent_id`` names it.
 
 ``--require SUBSTR`` (repeatable) exits nonzero unless at least one
 complete event's name contains the substring: CI's traced-smoke step
@@ -35,20 +38,30 @@ def load_events(path: str) -> list[dict]:
 
 
 def summarize(events: list[dict]) -> list[tuple]:
-    """[(name, count, total_us, mean_us, max_us)] sorted by total desc."""
-    agg: dict[str, list[float]] = {}
-    for ev in events:
-        if ev.get("ph") != "X":
-            continue
+    """[(name, count, total_us, self_us, mean_us, max_us, counts)]
+    sorted by total desc; ``counts`` sums the spans' ``args.counts``."""
+    spans = [ev for ev in events if ev.get("ph") == "X"]
+    children_us: dict = {}
+    for ev in spans:
+        parent = ev.get("args", {}).get("parent_id")
+        if parent:
+            children_us[parent] = (children_us.get(parent, 0.0)
+                                   + float(ev.get("dur", 0.0)))
+    agg: dict[str, list] = {}
+    for ev in spans:
+        args = ev.get("args", {})
         dur = float(ev.get("dur", 0.0))
-        row = agg.setdefault(ev["name"], [0, 0.0, 0.0])
+        row = agg.setdefault(ev["name"], [0, 0.0, 0.0, 0.0, {}])
         row[0] += 1
         row[1] += dur
-        row[2] = max(row[2], dur)
+        row[2] += max(0.0, dur - children_us.get(args.get("span_id"), 0.0))
+        row[3] = max(row[3], dur)
+        for k, v in args.get("counts", {}).items():
+            row[4][k] = row[4].get(k, 0) + v
     return sorted(
         (
-            (name, int(cnt), total, total / cnt, mx)
-            for name, (cnt, total, mx) in agg.items()
+            (name, int(cnt), total, own, total / cnt, mx, counts)
+            for name, (cnt, total, own, mx, counts) in agg.items()
         ),
         key=lambda r: -r[2],
     )
@@ -59,14 +72,15 @@ def format_table(rows: list[tuple]) -> str:
         return "(no complete spans in trace)"
     w = max(len(r[0]) for r in rows)
     lines = [
-        f"{'span':<{w}}  {'count':>7}  {'total_ms':>10}  "
-        f"{'mean_us':>10}  {'max_us':>10}"
+        f"{'span':<{w}}  {'count':>7}  {'total_ms':>10}  {'self_ms':>10}  "
+        f"{'mean_us':>10}  {'max_us':>10}  counts"
     ]
-    for name, cnt, total, mean, mx in rows:
+    for name, cnt, total, own, mean, mx, counts in rows:
+        tail = " ".join(f"{k}={v}" for k, v in sorted(counts.items()))
         lines.append(
             f"{name:<{w}}  {cnt:>7}  {total / 1e3:>10.3f}  "
-            f"{mean:>10.1f}  {mx:>10.1f}"
-        )
+            f"{own / 1e3:>10.3f}  {mean:>10.1f}  {mx:>10.1f}  {tail}"
+        .rstrip())
     return "\n".join(lines)
 
 

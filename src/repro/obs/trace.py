@@ -16,13 +16,32 @@ Usage::
     trace.configure(trace="on")            # or REPRO_TRACE=1
     with trace.span("cc.frontier.level", bucket=4096) as sp:
         ...                                # host-driven work
+        trace.count("host_sync")           # before each device read
         sp.tag(rounds=int(rounds))         # values the host ALREADY read
     trace.event("serve.quarantine", uid=7) # instant marker
     trace.export_chrome("trace.json")      # Chrome/Perfetto timeline
 
 * **Disabled is free.** ``span()`` returns one shared ``_NULL_SPAN``
-  singleton when tracing is off -- no allocation, no clock read, no
-  list append -- so instrumented hot loops cost nothing by default.
+  singleton and ``count()`` returns at once when tracing is off -- no
+  allocation, no clock read, no list append -- so instrumented hot
+  loops cost nothing by default.
+* **Parents.** The tracer keeps a per-thread stack of open spans. Every
+  recorded span carries ``args.span_id`` (unique within the tracer)
+  and ``args.parent_id`` (the enclosing span; 0 for a root), so the
+  spans of one call or wave share their root and a span's self time is
+  its duration less its children's.
+* **Counters.** ``count(name, n)`` adds to the innermost open span. A
+  span writes its totals as ``args.counts`` when it closes and folds
+  them into its parent, so each span's counts cover everything beneath
+  it.
+* **Profiler clock.** While tracing is on, every span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so any ``jax.profiler``
+  capture shows the program's spans beside the device ops.
+* **Compiles.** The first ``configure(trace="on")`` registers one
+  ``jax.monitoring`` listener that records JAX's lowerings as
+  ``jax.lower`` spans (``fun=`` the function) and its backend compiles
+  (cache fetches included) as ``jax.compile`` spans, each a child of
+  the span open when it happened.
 * **Device spans.** ``span(..., device=True)`` calls
   ``jax.block_until_ready`` at close on the value registered via
   ``sp.block_on(x)`` -- the RL006 block-timer discipline, applied at
@@ -33,11 +52,6 @@ Usage::
   span even when tracing is disabled (it times and blocks but records
   nothing): callers that need the duration regardless -- the training
   loop's straggler watchdog -- read ``sp.duration`` after the block.
-* **Profiler interplay.** ``span(..., profile=True)`` wraps the span
-  in ``jax.profiler.TraceAnnotation`` when the global ``profile``
-  knob is ``"on"``, so host spans line up with device traces in a
-  ``jax.profiler`` capture. Off by default: annotations are cheap but
-  not free, and only useful under an active profiler session.
 
 Exported Chrome-trace JSON (``{"traceEvents": [...]}``, complete
 events ``ph="X"``, instants ``ph="i"``, microsecond timestamps) loads
@@ -45,20 +59,28 @@ directly in ``chrome://tracing`` / Perfetto; ``python -m
 repro.obs.summarize trace.json`` prints the per-phase aggregate table.
 
 This module imports nothing from ``repro`` at module level (the
-engines it instruments import it), and never imports ``jax`` unless a
-device span actually has something to block on.
+engines it instruments import it), and imports ``jax`` only once
+tracing is turned on or a device span has something to block on.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
+import weakref
 
-# The RL004 choice sets for the tracing knobs (docs/engines.md matrix;
+# The RL004 choice set for the tracing knob (docs/engines.md matrix;
 # registered in tools/lint/passes/choice_set.py KNOBS).
 TRACE_MODES = ("off", "on")
-PROFILE_MODES = ("off", "on")
+
+# JAX's monitoring events for a lowering and for a backend compile
+# (a fetch from the persistent cache is timed under the latter too).
+_JAX_SPANS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
 
 
 class _NullSpan:
@@ -85,24 +107,32 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _add(span, name, n) -> None:
+    if span.counts is None:
+        span.counts = {}
+    span.counts[name] = span.counts.get(name, 0) + n
+
+
 class Span:
     """One live span. Use as a context manager; see module docstring."""
 
     __slots__ = (
-        "_tracer", "name", "attrs", "device", "profile", "_blockee",
-        "_ann", "_t0", "duration",
+        "_tracer", "name", "attrs", "device", "_blockee", "_ann", "_t0",
+        "duration", "span_id", "parent", "counts",
     )
 
-    def __init__(self, tracer, name, attrs, device, profile):
+    def __init__(self, tracer, name, attrs, device):
         self._tracer = tracer  # None: timer-only span (tracing disabled)
         self.name = name
         self.attrs = attrs
         self.device = device
-        self.profile = profile
         self._blockee = None
         self._ann = None
         self._t0 = 0
         self.duration = 0.0
+        self.span_id = 0
+        self.parent = None  # the enclosing open Span, if any
+        self.counts = None  # {counter: total}, made on the first count
 
     def tag(self, **attrs) -> "Span":
         """Attach attributes the host has ALREADY read (round counts,
@@ -117,10 +147,15 @@ class Span:
         return value
 
     def __enter__(self):
-        if self.profile and self._tracer is not None:
-            from jax.profiler import TraceAnnotation
-
-            self._ann = TraceAnnotation(self.name)
+        tracer = self._tracer
+        if tracer is not None:
+            stack = tracer._stack()
+            self.parent = stack[-1] if stack else None
+            self.span_id = next(tracer._ids)
+            stack.append(self)
+            # Entered last, just before the clock read, so the native
+            # annotation and the span start on the same instant.
+            self._ann = tracer._annotation(self.name)
             self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
@@ -132,12 +167,23 @@ class Span:
             jax.block_until_ready(self._blockee)
         end = time.perf_counter_ns()
         self.duration = (end - self._t0) * 1e-9
-        if self._ann is not None:
+        tracer = self._tracer
+        if tracer is not None:
             self._ann.__exit__(exc_type, exc, tb)
-        if self._tracer is not None:
+            stack = tracer._stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            attrs, parent = self.attrs, self.parent
             if exc_type is not None:
-                self.attrs.setdefault("exception", exc_type.__name__)
-            self._tracer._record(self.name, self._t0, end, self.attrs)
+                attrs.setdefault("exception", exc_type.__name__)
+            if self.counts:
+                attrs["counts"] = self.counts
+                if parent is not None:
+                    for name, n in self.counts.items():
+                        _add(parent, name, n)
+            attrs["span_id"] = self.span_id
+            attrs["parent_id"] = parent.span_id if parent is not None else 0
+            tracer._record(self.name, self._t0, end, attrs)
         return False
 
 
@@ -145,37 +191,52 @@ class Tracer:
     """Span/event collector. The module-level functions drive one
     process-global instance; tests may build their own."""
 
-    def __init__(self, *, trace: str = "off", profile: str = "off"):
+    def __init__(self, *, trace: str = "off"):
         self.events: list[dict] = []
         self._origin = time.perf_counter_ns()
         self._pid = os.getpid()
-        self.configure(trace=trace, profile=profile)
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+        self._annotation = None  # jax.profiler.TraceAnnotation, once on
+        self.enabled = False
+        self.configure(trace=trace)
 
     # -- knobs ---------------------------------------------------------
-    def configure(
-        self, *, trace: str | None = None, profile: str | None = None
-    ) -> None:
-        """Set the ``trace=`` / ``profile=`` modes (``docs/engines.md``
-        matrix; unknown strings raise like every other dispatch knob)."""
-        # check_choice imports lazily, and only to raise: the engines
-        # this module instruments import it, so a module-level (or
-        # valid-path) import of repro.core here would be a cycle.
-        if trace is not None:
-            if trace not in TRACE_MODES:
-                from repro.core.components import check_choice
+    def configure(self, *, trace: str | None = None) -> None:
+        """Set the ``trace=`` mode (``docs/engines.md`` matrix; unknown
+        strings raise like every other dispatch knob)."""
+        if trace is None:
+            return
+        if trace not in TRACE_MODES:
+            # check_choice imports lazily, and only to raise: the engines
+            # this module instruments import it, so a module-level (or
+            # valid-path) import of repro.core here would be a cycle.
+            from repro.core.components import check_choice
 
-                check_choice("trace", trace, TRACE_MODES)
-            self.trace = trace
-        if profile is not None:
-            if profile not in PROFILE_MODES:
-                from repro.core.components import check_choice
+            check_choice("trace", trace, TRACE_MODES)
+        self.trace = trace
+        self.enabled = trace == "on"
+        if self.enabled and self._annotation is None:
+            self._listen_to_jax()
 
-                check_choice("profile", profile, PROFILE_MODES)
-            self.profile = profile
+    def _listen_to_jax(self) -> None:
+        """Import jax's profiler annotation and register one
+        ``jax.monitoring`` listener per tracer, on the first
+        ``trace="on"``; the listener holds the tracer weakly (JAX keeps
+        its listeners for the life of the process) and does nothing
+        while tracing is off."""
+        import jax
+        from jax.profiler import TraceAnnotation
 
-    @property
-    def enabled(self) -> bool:
-        return self.trace == "on"
+        self._annotation = TraceAnnotation
+        ref = weakref.ref(self)
+
+        def on_event(event, duration, fun_name="?", **_):
+            tracer = ref()
+            if tracer is not None and tracer.enabled and event in _JAX_SPANS:
+                tracer._jax_span(_JAX_SPANS[event], duration, fun_name)
+
+        jax.monitoring.register_event_duration_secs_listener(on_event)
 
     def reset(self) -> None:
         """Drop recorded events (fresh timeline, same knobs)."""
@@ -183,12 +244,18 @@ class Tracer:
         self._origin = time.perf_counter_ns()
 
     # -- recording -----------------------------------------------------
+    def _stack(self) -> list:
+        """This thread's open spans, innermost last."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def span(
         self,
         name: str,
         *,
         device: bool = False,
-        profile: bool = False,
         timer: bool = False,
         **attrs,
     ):
@@ -197,11 +264,17 @@ class Tracer:
         if not self.enabled:
             if not timer:
                 return _NULL_SPAN
-            return Span(None, name, attrs, device, False)
-        return Span(
-            self, name, attrs, device,
-            profile and self.profile == "on",
-        )
+            return Span(None, name, attrs, device)
+        return Span(self, name, attrs, device)
+
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to counter ``name`` of the innermost open span
+        (dropped where none is open)."""
+        if not self.enabled:
+            return
+        stack = self._stack()
+        if stack:
+            _add(stack[-1], name, n)
 
     def event(self, name: str, **attrs) -> None:
         """An instant marker (Chrome-trace ``ph="i"``)."""
@@ -213,6 +286,16 @@ class Tracer:
             "ts": (now - self._origin) / 1e3,
             "pid": self._pid, "tid": threading.get_ident(),
             "args": attrs,
+        })
+
+    def _jax_span(self, name, duration_s, fun_name) -> None:
+        """A span JAX timed itself: it ends now and began ``duration_s``
+        earlier, under the innermost open span."""
+        end = time.perf_counter_ns()
+        stack = self._stack()
+        self._record(name, end - int(duration_s * 1e9), end, {
+            "fun": fun_name, "span_id": next(self._ids),
+            "parent_id": stack[-1].span_id if stack else 0,
         })
 
     def _record(self, name, t0_ns, end_ns, attrs) -> None:
@@ -238,19 +321,15 @@ class Tracer:
 
 
 # The process-global tracer the engines record into. REPRO_TRACE=1 (or
-# "on") enables tracing from the environment -- the benchmark / CI
-# hook; REPRO_PROFILE=1 additionally arms TraceAnnotation wrapping.
+# "on") enables tracing from the environment -- the benchmark / CI hook.
 _ON = ("1", "on", "true", "yes")
 _GLOBAL = Tracer(
     trace="on" if os.environ.get("REPRO_TRACE", "").lower() in _ON else "off",
-    profile=(
-        "on" if os.environ.get("REPRO_PROFILE", "").lower() in _ON else "off"
-    ),
 )
 
 
-def configure(*, trace: str | None = None, profile: str | None = None):
-    _GLOBAL.configure(trace=trace, profile=profile)
+def configure(*, trace: str | None = None):
+    _GLOBAL.configure(trace=trace)
 
 
 def enabled() -> bool:
@@ -266,6 +345,7 @@ def reset() -> None:
 # call frame + kwargs packing per span. _GLOBAL is never reassigned
 # (configure mutates it), so the bindings cannot go stale.
 span = _GLOBAL.span
+count = _GLOBAL.count
 event = _GLOBAL.event
 
 

@@ -531,9 +531,9 @@ class GraphServeEngine(WaveScheduler):
             # ConvergenceError sentinel fires for this wave.
             kw["max_rounds"] = 0
         # The engine span covers the batched device program AND the
-        # np.asarray materializations -- those reads are the wave's
-        # existing host sync, so the span closes on an already-synced
-        # boundary (no block_on needed).
+        # readback (its own ``serve.wave.readback`` span) -- those reads
+        # are the wave's existing host sync, so the span closes on an
+        # already-synced boundary (no block_on needed).
         with trace.span(
             "serve.wave.engine", stage=stage, requests=len(wave),
             node_cap=node_cap, edge_cap=edge_cap, new_bucket=new_bucket,
@@ -543,7 +543,6 @@ class GraphServeEngine(WaveScheduler):
                 labels, rounds = connected_components(
                     src, dst, node_cap, **kw
                 )
-                labels = np.asarray(labels)
                 edge_u = edge_v = None
             elif stage == "forest":
                 forest = spanning_forest(src, dst, node_cap, **kw)
@@ -561,14 +560,17 @@ class GraphServeEngine(WaveScheduler):
                 labels, rounds = ta.forest.labels, ta.forest.rounds
                 edge_u, edge_v = ta.forest.edge_u, ta.forest.edge_v
                 extras = (
-                    np.asarray(ta.parent),
-                    np.asarray(ta.depth),
-                    np.asarray(ta.subtree_size),
-                    np.asarray(ta.computations.preorder),
-                    np.asarray(ta.computations.postorder),
+                    ta.parent, ta.depth, ta.subtree_size,
+                    ta.computations.preorder, ta.computations.postorder,
                 )
-            labels = np.asarray(labels)
-            esp.tag(rounds=int(rounds))
+            with trace.span("serve.wave.readback", stage=stage):
+                if stage == "cc":  # the forest stages read these already
+                    trace.count("host_sync", 2)
+                    labels, rounds = np.asarray(labels), int(rounds)
+                if extras is not None:
+                    trace.count("host_sync", len(extras))
+                    extras = tuple(np.asarray(x) for x in extras)
+            esp.tag(rounds=rounds)
 
         with trace.span("serve.wave.unpack", requests=len(wave)):
             self._unpack(wave, node_off, labels, edge_u, edge_v, extras)
@@ -581,7 +583,7 @@ class GraphServeEngine(WaveScheduler):
             requests=len(wave), stage=stage,
             num_nodes=n_union, num_edges=m_union,
             node_cap=node_cap, edge_cap=edge_cap,
-            new_bucket=new_bucket, rounds=int(rounds),
+            new_bucket=new_bucket, rounds=rounds,
         )
         self.wave_records.append(rec)
         rec.publish(self.metrics)

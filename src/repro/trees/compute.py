@@ -42,6 +42,7 @@ from repro.core.list_ranking import (
     select_splitters,
     wylie_rank,
 )
+from repro.obs import trace
 from repro.trees.forest import SpanningForest, spanning_forest
 from repro.trees.tour import EulerTour, euler_tour, tour_capacity
 
@@ -73,6 +74,7 @@ def tour_splitters(
     if tour.num_arcs:
         # mask, don't slice: padded-edge-buffer tours interleave dead
         # self-loop arcs with the real ones (see ``euler_tour``)
+        trace.count("host_sync", 2)
         heads = np.unique(
             np.asarray(tour.head_of_arc, dtype=np.int64)[
                 np.asarray(tour.valid)
@@ -352,9 +354,10 @@ def tree_analytics(
     All quantities are exact int32: results are bit-identical across
     every engine combination.
     """
-    forest = spanning_forest(
-        src, dst, num_nodes, engine=engine, mesh=mesh, **cc_kwargs
-    )
+    with trace.span("trees.forest"):
+        forest = spanning_forest(
+            src, dst, num_nodes, engine=engine, mesh=mesh, **cc_kwargs
+        )
     edge_u, edge_v, num_edges = forest.edge_u, forest.edge_v, None
     if pad_edges_to is not None:
         f = forest.num_edges
@@ -367,12 +370,17 @@ def tree_analytics(
         edge_v = np.zeros((pad_edges_to,), np.int32)
         edge_u[:f] = forest.edge_u
         edge_v[:f] = forest.edge_v
-    tour = euler_tour(
-        edge_u, edge_v, num_nodes,
-        labels=forest.labels, pad_to=pad_to, num_edges=num_edges,
-    )
-    comp = tree_computations(
-        tour, rank_engine=rank_engine, kernel_impl=kernel_impl,
-        num_splitters=num_splitters, seed=seed, mesh=mesh,
-    )
+    with trace.span("trees.tour"):
+        tour = euler_tour(
+            edge_u, edge_v, num_nodes,
+            labels=forest.labels, pad_to=pad_to, num_edges=num_edges,
+        )
+    rank_kwargs = dict(rank_engine=rank_engine, kernel_impl=kernel_impl,
+                       num_splitters=num_splitters, seed=seed, mesh=mesh)
+    ranks = None
+    if tour.capacity and tour.num_arcs:  # else tree_computations' trivial path
+        with trace.span("trees.rank"):
+            ranks = tour_ranks(tour, **rank_kwargs)
+    with trace.span("trees.compute"):
+        comp = tree_computations(tour, ranks=ranks, **rank_kwargs)
     return TreeAnalytics(forest=forest, tour=tour, computations=comp)

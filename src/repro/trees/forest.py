@@ -13,7 +13,10 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import numpy as np
+
+from repro.obs import trace
 
 
 @dataclass
@@ -46,6 +49,10 @@ def forest_from_hooks(
 ) -> SpanningForest:
     """Compact raw ``(hook_u, hook_v)`` slot arrays (sentinel n = never
     hooked) into a ``SpanningForest`` (host-side)."""
+    if trace.enabled():  # one host sync per device value read below
+        trace.count("host_sync", sum(
+            isinstance(x, jax.Array) for x in (hook_u, hook_v, labels, rounds)
+        ))
     hu = np.asarray(hook_u)
     hv = np.asarray(hook_v)
     mask = hu < num_nodes

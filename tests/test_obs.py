@@ -2,7 +2,10 @@
 
 Covers the layer's contracts: disabled tracing is the shared no-op
 singleton (zero allocation, zero events), spans nest with monotonic
-Chrome-trace timestamps, the exported JSON round-trips, the metrics
+Chrome-trace timestamps and parent links, counters fold into parents,
+JAX lowerings become child spans, spans show in a profiler capture,
+the engines' host-sync counts match hand counts, the exported JSON
+round-trips, the metrics
 snapshot of two identical fault-injected serve runs is identical, and
 the instrumentation adds NO device->host sync (the RL001 lint pass
 over the instrumented tree, plus a traced jitted-CC runtime smoke).
@@ -56,10 +59,34 @@ def test_configure_rejects_unknown_modes():
     t = Tracer()
     with pytest.raises(ValueError, match="trace"):
         t.configure(trace="loud")
-    with pytest.raises(ValueError, match="profile"):
-        t.configure(profile="always")
-    t.configure(trace="on", profile="off")
+    with pytest.raises(ValueError, match="trace"):
+        Tracer(trace="always")
+    t.configure(trace="on")
     assert t.enabled
+    t.configure(trace="off")
+    assert not t.enabled
+
+
+def test_disabled_count_records_and_allocates_nothing():
+    import tracemalloc
+
+    from repro.obs import trace as trace_mod
+
+    t = Tracer()
+    with t.span("a"):
+        t.count("host_sync")
+    tracemalloc.start()
+    try:
+        for _ in range(1000):
+            t.count("host_sync")
+            t.count("host_sync", 4)
+        snap = tracemalloc.take_snapshot().filter_traces(
+            [tracemalloc.Filter(True, trace_mod.__file__)]
+        )
+    finally:
+        tracemalloc.stop()
+    assert sum(st.size for st in snap.statistics("filename")) == 0
+    assert t.events == []
 
 
 # ---------------------------------------------------------------------------
@@ -85,9 +112,87 @@ def test_nested_spans_monotonic_and_contained():
     for child in (inner0, inner1):
         assert outer["ts"] <= child["ts"]
         assert child["ts"] + child["dur"] <= outer["ts"] + outer["dur"] + 1e-6
-    assert outer["args"] == {"n": 2}
-    assert inner1["args"] == {"i": 1}
+    assert outer["args"] == {"n": 2, "span_id": 1, "parent_id": 0}
+    assert inner1["args"] == {"i": 1, "span_id": 3, "parent_id": 1}
     assert marker["ph"] == "i"
+
+
+def test_span_parent_links_and_counts_fold_into_parents():
+    t = Tracer(trace="on")
+    with t.span("root"):
+        t.count("host_sync")
+        with t.span("mid"):
+            with t.span("leaf"):
+                t.count("host_sync", 2)
+                t.count("other")
+            t.count("host_sync")
+        with t.span("quiet"):
+            pass
+    t.count("host_sync")  # no open span: dropped
+    ev = {e["name"]: e["args"] for e in t.events}
+    ids = {name: a["span_id"] for name, a in ev.items()}
+    assert len(set(ids.values())) == 4
+    assert ev["root"]["parent_id"] == 0
+    assert ev["mid"]["parent_id"] == ids["root"]
+    assert ev["leaf"]["parent_id"] == ids["mid"]
+    assert ev["quiet"]["parent_id"] == ids["root"]
+    assert ev["leaf"]["counts"] == {"host_sync": 2, "other": 1}
+    assert ev["mid"]["counts"] == {"host_sync": 3, "other": 1}
+    assert ev["root"]["counts"] == {"host_sync": 4, "other": 1}
+    assert "counts" not in ev["quiet"]
+
+
+def test_span_stacks_are_per_thread():
+    import threading
+
+    t = Tracer(trace="on")
+    with t.span("main"):
+        th = threading.Thread(target=lambda: t.span("worker").__enter__()
+                              .__exit__(None, None, None))
+        th.start()
+        th.join(timeout=30)
+    assert not th.is_alive()
+    ev = {e["name"]: e["args"] for e in t.events}
+    assert ev["worker"]["parent_id"] == 0
+
+
+def test_fresh_jit_lowering_is_a_child_span():
+    import jax
+
+    x = jnp.arange(16)
+    t = Tracer(trace="on")
+    with t.span("step"):
+        jax.jit(lambda v: v * 3 + 1)(x).block_until_ready()
+    t.configure(trace="off")
+    jax.jit(lambda v: v * 5 + 1)(x).block_until_ready()  # off: unrecorded
+    step = next(e for e in t.events if e["name"] == "step")
+    lowers = [e for e in t.events if e["name"] == "jax.lower"]
+    assert len(lowers) == 1
+    assert lowers[0]["args"]["parent_id"] == step["args"]["span_id"]
+    assert "<lambda>" in lowers[0]["args"]["fun"]
+    compiles = [e for e in t.events if e["name"] == "jax.compile"]
+    assert [c["args"]["parent_id"] for c in compiles] == [
+        step["args"]["span_id"]]
+    for e in lowers + compiles:  # inside the step, on the tracer's clock
+        assert step["ts"] <= e["ts"] + 1.0
+        assert e["ts"] + e["dur"] <= step["ts"] + step["dur"] + 1.0
+
+
+def test_spans_show_in_a_profiler_capture(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    t = Tracer(trace="on")
+    with jax.profiler.trace(str(tmp_path)):
+        with t.span("obs.capture.probe"):
+            jnp.arange(8).block_until_ready()
+    paths = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    assert paths
+    names = {ev.name for plane in ProfileData.from_file(paths[0]).planes
+             for line in plane.lines for ev in line.events}
+    assert "obs.capture.probe" in names
 
 
 def test_span_records_exception_tag():
@@ -110,7 +215,8 @@ def test_chrome_export_round_trips(tmp_path):
     evs = doc["traceEvents"]
     assert {e["ph"] for e in evs} == {"X", "i"}
     x = next(e for e in evs if e["ph"] == "X")
-    assert x["name"] == "work" and x["dur"] >= 0 and x["args"] == {"k": 1}
+    assert x["name"] == "work" and x["dur"] >= 0
+    assert x["args"] == {"k": 1, "span_id": 1, "parent_id": 0}
 
 
 def test_summarize_table_and_require(tmp_path, capsys):
@@ -122,12 +228,34 @@ def test_summarize_table_and_require(tmp_path, capsys):
     t.export_chrome(str(path))
     rows = summarize(t.events)
     assert rows == [("serve.wave", 3, pytest.approx(rows[0][2]),
-                     pytest.approx(rows[0][3]), pytest.approx(rows[0][4]))]
+                     pytest.approx(rows[0][2]), pytest.approx(rows[0][4]),
+                     pytest.approx(rows[0][5]), {})]
     assert "serve.wave" in format_table(rows)
     assert main([str(path), "--require", "serve.wave"]) == 0
     capsys.readouterr()
     assert main([str(path), "--require", "serve.bisect"]) == 1
     assert "REQUIRE FAIL" in capsys.readouterr().err
+
+
+def test_summarize_self_time_and_counts():
+    def x(name, dur, sid, parent, **counts):
+        args = {"span_id": sid, "parent_id": parent}
+        if counts:
+            args["counts"] = counts
+        return {"name": name, "ph": "X", "ts": 0.0, "dur": dur, "args": args}
+
+    events = [x("leaf", 30.0, 3, 2, host_sync=2), x("lower", 5.0, 4, 2),
+              x("mid", 50.0, 2, 1, host_sync=2), x("leaf", 10.0, 5, 1),
+              x("root", 100.0, 1, 0, host_sync=3),
+              {"name": "mark", "ph": "i", "ts": 1.0, "args": {}}]
+    rows = {r[0]: r for r in summarize(events)}
+    assert rows["root"][1:4] == (1, 100.0, 40.0)
+    assert rows["mid"][1:4] == (1, 50.0, 15.0)
+    assert rows["leaf"][1:4] == (2, 40.0, 40.0)
+    assert rows["leaf"][6] == {"host_sync": 2}
+    assert rows["root"][6] == {"host_sync": 3}
+    table = format_table(list(rows.values()))
+    assert "self_ms" in table and "host_sync=3" in table
 
 
 # ---------------------------------------------------------------------------
@@ -279,3 +407,102 @@ def test_instrumented_tree_adds_no_host_syncs():
     )
     new, _old, stale = split_baselined(findings, baseline)
     assert [f.format() for f in new] == []
+
+
+# ---------------------------------------------------------------------------
+# program spans and the host-sync counter
+# ---------------------------------------------------------------------------
+
+# A 1024-node chain through the pre-pass and a 16-edge floor climbs this
+# ladder: (bucket, rounds) per level, the last one converging.
+CHAIN_LADDER = [(1024, 1), (256, 4), (32, 1)]
+
+
+def _chain_call(**kw):
+    from repro.core import connected_components
+
+    src = np.arange(1023, dtype=np.int32)
+    return connected_components(src, src + 1, 1024, engine="frontier",
+                                min_bucket=16, sample_rounds=2, **kw)
+
+
+def _traced(fn):
+    trace.reset()
+    trace.configure(trace="on")
+    try:
+        out = fn()
+        events = trace.chrome_trace()["traceEvents"]
+    finally:
+        trace.configure(trace="off")
+        trace.reset()
+    return out, events
+
+
+def test_frontier_call_syncs_match_the_hand_count():
+    """Pre-pass live count (1); rounds + changed + s on each level that
+    does not converge (3 + 3) and rounds + changed on the last (2); a
+    live count before each of the two shrinks (2); the final s (1)."""
+    assert _chain_call(with_stats=True)[2].levels == CHAIN_LADDER
+    _, events = _traced(_chain_call)
+    by_id = {e["args"]["span_id"]: e for e in events}
+    (root,) = [e for e in events if e["name"] == "cc.call"]
+    assert root["args"]["parent_id"] == 0
+    assert root["args"]["engine"] == "frontier"
+    assert root["args"]["counts"]["host_sync"] == 1 + 3 + 3 + 2 + 2 + 1 == 12
+    for name, parent in [("cc.dedup", "cc.call"), ("cc.upload", "cc.call"),
+                         ("cc.frontier.sample", "cc.call"),
+                         ("cc.frontier.sample.permute", "cc.frontier.sample"),
+                         ("cc.frontier", "cc.call"),
+                         ("cc.frontier.level", "cc.frontier"),
+                         ("cc.compress", "cc.frontier")]:
+        spans = [e for e in events if e["name"] == name]
+        assert spans, name
+        assert {by_id[e["args"]["parent_id"]]["name"] for e in spans} == {parent}
+
+
+def test_traced_analytics_waves_time_each_stage():
+    from repro.data.graphs import graph_request_stream
+    from repro.serve import GraphRequest, GraphServeEngine
+
+    def serve():
+        eng = GraphServeEngine(max_requests=3)
+        for i, g in enumerate(graph_request_stream(
+                7, kind="analytics", family="random", seed=5)):
+            eng.submit(GraphRequest(uid=i, **g))
+        eng.run()
+        return eng
+
+    eng, events = _traced(serve)
+    by_id = {e["args"]["span_id"]: e for e in events if e["ph"] == "X"}
+    parent = {e["name"]: by_id[e["args"]["parent_id"]]["name"]
+              for e in by_id.values() if e["args"]["parent_id"]}
+    assert parent["trees.forest"] == "serve.wave.engine"
+    assert parent["cc.call"] == "trees.forest"
+    for name in ("trees.tour", "trees.rank", "trees.compute",
+                 "serve.wave.readback"):
+        assert parent[name] == "serve.wave.engine"
+    waves = [e for e in events if e["name"] == "serve.wave"]
+    assert len(waves) == eng.waves == 3
+    # Per wave: the dense CC's convergence flag (1), the forest's hook
+    # slots, labels and rounds (4), the five tree arrays (5).
+    for w in waves:
+        assert w["args"]["counts"] == {"host_sync": 10}
+    (run,) = [e for e in events if e["name"] == "serve.run"]
+    assert run["args"]["counts"] == {"host_sync": 30}
+
+
+def test_every_host_sync_pragma_is_counted():
+    """Each ``disable=host-sync`` line of the CC engines has a
+    ``trace.count("host_sync")`` on one of the three lines above it,
+    one count per pragma, so a new sync cannot land uncounted."""
+    for rel in ("src/repro/core/frontier.py", "src/repro/core/components.py"):
+        with open(os.path.join(_ROOT, rel)) as f:
+            lines = f.read().splitlines()
+        counts = [i for i, ln in enumerate(lines)
+                  if ln.strip() == 'trace.count("host_sync")']
+        pragmas = [i for i, ln in enumerate(lines)
+                   if "repro-lint: disable=host-sync" in ln]
+        assert pragmas, rel
+        assert len(counts) == len(pragmas), rel
+        for i in pragmas:
+            assert any(i - 3 <= c < i for c in counts), f"{rel}:{i + 1}"

@@ -42,7 +42,6 @@ KNOBS = {
     "on_overflow": ("src/repro/serve/engine.py", "OVERFLOW_POLICIES"),
     "on_failure": ("src/repro/serve/waves.py", "FAILURE_POLICIES"),
     "trace": ("src/repro/obs/trace.py", "TRACE_MODES"),
-    "profile": ("src/repro/obs/trace.py", "PROFILE_MODES"),
 }
 
 DOCS_REL = "docs/engines.md"

@@ -373,14 +373,35 @@ def dedup_edges(
     Self-loops can never hook (SV2 needs Db < Da, SV3 Da != Db) and
     duplicates min-hook idempotently, so removing them changes neither
     labels nor round count -- it only shrinks the 2m edge walk.
+
+    Returns the distinct ``(lo, hi)`` pairs, ``lo < hi``, as int32 in
+    lexicographic order; ids must fit int32, the engines' label dtype.
+    Each edge is packed into one int64 key, ``lo * 2**32 + (hi + 2**31)``,
+    which sorts exactly as the pair does, negative ids included: one
+    flat integer sort stands in for a row-wise (byte-compared) unique,
+    and the int32 work arrays and in-place packing keep the fresh
+    int64 buffers to two.
     """
-    e = np.stack(
-        [np.asarray(src).ravel(), np.asarray(dst).ravel()], axis=1
-    ).astype(np.int64)
-    lo, hi = e.min(axis=1), e.max(axis=1)
+    u = np.asarray(src, dtype=np.int32).ravel()
+    v = np.asarray(dst, dtype=np.int32).ravel()
+    lo, hi = np.minimum(u, v), np.maximum(u, v)
     keep = lo != hi
-    u = np.unique(np.stack([lo[keep], hi[keep]], axis=1), axis=0)
-    return u[:, 0].astype(np.int32), u[:, 1].astype(np.int32)
+    key = lo[keep].astype(np.int64)
+    key <<= 32
+    key += hi[keep]
+    key += 2**31
+    key.sort()
+    if key.size:  # keep the first key of each run of equal keys
+        first = np.empty(key.size, bool)
+        first[0] = True
+        np.not_equal(key[1:], key[:-1], out=first[1:])
+        key = key[first]
+    # The low word holds hi + 2**31; as int32 that is hi with its sign
+    # bit flipped, so flipping it back recovers hi.
+    hi = key.astype(np.int32)
+    hi ^= np.int32(-2**31)
+    key >>= 32
+    return key.astype(np.int32), hi
 
 
 def _maybe_dedup(src, dst, dedup: bool):
@@ -394,8 +415,11 @@ def _maybe_dedup(src, dst, dedup: bool):
     )
     if not dedup or not host:
         return src, dst
-    with trace.span("cc.dedup"):
-        return dedup_edges(src, dst)
+    with trace.span("cc.dedup") as sp:
+        a, b = dedup_edges(src, dst)
+        if trace.enabled():
+            sp.tag(m_in=np.size(src), m_out=a.size)
+        return a, b
 
 
 @partial(

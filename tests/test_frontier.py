@@ -144,6 +144,69 @@ def test_dedup_edges_degenerate_inputs():
     assert a.tolist() == [1] and b.tolist() == [2]
 
 
+def _dedup_edges_stacked(src, dst):
+    """The row-wise ``np.unique(axis=0)`` dedup that ``dedup_edges``'s
+    packed-key sort replaced, kept verbatim as its reference."""
+    e = np.stack(
+        [np.asarray(src).ravel(), np.asarray(dst).ravel()], axis=1
+    ).astype(np.int64)
+    lo, hi = e.min(axis=1), e.max(axis=1)
+    keep = lo != hi
+    u = np.unique(np.stack([lo[keep], hi[keep]], axis=1), axis=0)
+    return u[:, 0].astype(np.int32), u[:, 1].astype(np.int32)
+
+
+def _assert_dedup_matches_stacked(src, dst):
+    a, b = dedup_edges(src, dst)
+    ra, rb = _dedup_edges_stacked(src, dst)
+    assert a.dtype == np.int32 and b.dtype == np.int32
+    np.testing.assert_array_equal(a, ra)  # same edges, same order
+    np.testing.assert_array_equal(b, rb)
+
+
+_INT32_ENDS = [-2**31, -2**31 + 1, -2, -1, 0, 1, 2**31 - 2, 2**31 - 1]
+
+
+@pytest.mark.parametrize("case", ["random", "int32_ends", "int64", "sequences"])
+def test_dedup_edges_matches_stacked_unique(case):
+    r = np.random.default_rng(11)
+    if case == "int32_ends":  # every pair of ids at both ends of int32
+        src, dst = (x.ravel() for x in np.meshgrid(_INT32_ENDS, _INT32_ENDS))
+        src, dst = src.astype(np.int32), dst.astype(np.int32)
+    else:  # duplicates, both orientations and self-loops
+        src = r.integers(0, 50, 2000).astype(np.int32)
+        dst = np.where(r.random(2000) < 0.1, src, r.integers(0, 50, 2000))
+        dst = dst.astype(np.int32)
+    if case == "int64":
+        src, dst = src.astype(np.int64), dst.astype(np.int64)
+    if case == "sequences":
+        src, dst = src.tolist(), tuple(dst.tolist())
+    _assert_dedup_matches_stacked(src, dst)
+
+
+_EDGE_ID = st.one_of(
+    st.sampled_from(_INT32_ENDS), st.integers(-2**31, 2**31 - 1),
+    st.integers(0, 7),  # a small pool, so random pairs repeat
+)
+
+
+@settings(max_examples=200, deadline=None)
+@given(
+    st.lists(st.tuples(_EDGE_ID, _EDGE_ID), max_size=40),
+    st.sampled_from([np.int32, np.int64, list, tuple]),
+)
+def test_dedup_edges_matches_stacked_unique_property(pairs, kind):
+    # Each pair also comes reversed and as a self-loop on its first end.
+    edges = pairs + [(v, u) for u, v in pairs] + [(u, u) for u, _ in pairs]
+    src = [u for u, _ in edges]
+    dst = [v for _, v in edges]
+    if kind in (np.int32, np.int64):
+        src, dst = np.array(src, kind), np.array(dst, kind)
+    else:
+        src, dst = kind(src), kind(dst)
+    _assert_dedup_matches_stacked(src, dst)
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.integers(1, 40), st.integers(0, 120), st.integers(0, 10_000))
 def test_dedup_never_changes_labels_or_rounds(n, m, seed):

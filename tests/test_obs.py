@@ -460,6 +460,26 @@ def test_frontier_call_syncs_match_the_hand_count():
         assert {by_id[e["args"]["parent_id"]]["name"] for e in spans} == {parent}
 
 
+def test_dedup_span_tags_its_edge_counts(monkeypatch):
+    """Tracing on: one ``cc.dedup`` span per host-input call, tagged with
+    the edges it saw and kept. Tracing off: the tags are never built."""
+    from repro.core import connected_components, dedup_edges
+    from repro.obs.trace import _NullSpan
+
+    src = np.array([0, 1, 1, 2, 3, 3, 3, 5], np.int32)
+    dst = np.array([1, 0, 1, 3, 2, 2, 3, 6], np.int32)  # dups + self-loops
+    _, events = _traced(lambda: connected_components(src, dst, 8))
+    (sp,) = [e for e in events if e["name"] == "cc.dedup"]
+    assert sp["args"]["m_in"] == 8
+    assert sp["args"]["m_out"] == dedup_edges(src, dst)[0].size == 3
+
+    tagged = []
+    monkeypatch.setattr(_NullSpan, "tag",
+                        lambda self, **attrs: tagged.append(attrs) or self)
+    connected_components(src, dst, 8)
+    assert not any("m_in" in attrs or "m_out" in attrs for attrs in tagged)
+
+
 def test_traced_analytics_waves_time_each_stage():
     from repro.data.graphs import graph_request_stream
     from repro.serve import GraphRequest, GraphServeEngine

@@ -255,9 +255,10 @@ def bellman_ford(
     from repro.compat import is_tracer
 
     n = num_nodes
-    a, b, w2 = _prep_edges(src, dst, weights)
+    with trace.span("sssp.prep"):
+        a, b, w2 = _prep_edges(src, dst, weights)
+        srcs, scalar = _prep_sources(sources, n)
     m2 = int(a.shape[0])
-    srcs, scalar = _prep_sources(sources, n)
     bound = max_rounds if max_rounds is not None else sssp_round_bound(n)
     # Whole-run device span; blocks at close on the same terminal sync
     # the convergence-sentinel read below already pays.
@@ -273,6 +274,7 @@ def bellman_ford(
     if not is_tracer(converged):
         # Intentional terminal sync: the sentinel must be read before
         # wrong distances can escape (core.components.ConvergenceError).
+        trace.count("host_sync")
         if not bool(converged):  # repro-lint: disable=host-sync
             raise ConvergenceError(
                 f"bellman_ford hit max_rounds={bound} before the "
@@ -283,6 +285,7 @@ def bellman_ford(
         dist, parent = dist[0], parent[0]
     if with_stats:
         # Terminal readback only when stats are asked for.
+        trace.count("host_sync")
         r = int(rounds)  # repro-lint: disable=host-sync
         stats = SsspStats(
             rounds=r, relax_visits=m2 * r, mask_visits=0, m2=m2,
@@ -313,9 +316,10 @@ def frontier_bellman_ford(
     level-synchronous design), so it cannot run inside ``jax.jit``.
     """
     n = num_nodes
-    a, b, w2 = _prep_edges(src, dst, weights)
+    with trace.span("sssp.prep"):
+        a, b, w2 = _prep_edges(src, dst, weights)
+        srcs, scalar = _prep_sources(sources, n)
     m2 = int(a.shape[0])
-    srcs, scalar = _prep_sources(sources, n)
     S = int(srcs.shape[0])
     bound = max_rounds if max_rounds is not None else sssp_round_bound(n)
     dist = _init_dist(jnp.asarray(srcs), n=n)
@@ -341,8 +345,10 @@ def frontier_bellman_ford(
                 return 0
             fmask = _edge_frontier(a, changed_nodes)
             stats.mask_visits += m2
+            trace.count("sssp.mask_edges", m2)
             # The level-synchronous sync: the host reads the live count
             # to pick the next power-of-two bucket.
+            trace.count("host_sync")
             return int(jnp.sum(fmask.astype(jnp.int32)))  # repro-lint: disable=host-sync
 
         def relax(live):
@@ -352,6 +358,7 @@ def frontier_bellman_ford(
                 ca, cb, cw = compact_weighted(a, b, w2, fmask, size=size)
                 dist, changed_nodes = _relax_level(ca, cb, cw, dist)
             stats.relax_visits += size
+            trace.count("sssp.relax_slots", size)
             stats.levels.append((size, live))
 
         def bound_hit(live, _rounds):
@@ -371,7 +378,12 @@ def frontier_bellman_ford(
         )
         run_sp.tag(rounds=rounds, levels=len(stats.levels))
     stats.rounds = rounds
-    parent = _min_parents(a, b, w2, dist, jnp.asarray(srcs))
+    # The post-pass over every arc; the span blocks on it only while
+    # tracing is on (the last live-count read already synced the loop).
+    with trace.span("sssp.parents", device=True) as par_sp:
+        parent = par_sp.block_on(
+            _min_parents(a, b, w2, dist, jnp.asarray(srcs))
+        )
     if scalar:
         dist, parent = dist[0], parent[0]
     out = (dist, parent, jnp.int32(rounds))
@@ -424,23 +436,25 @@ def shortest_paths(
     tracing = is_tracer(src) or is_tracer(dst) or is_tracer(weights)
     if engine == "auto":
         engine = "dense" if tracing else "frontier"
-    if engine == "frontier":
-        if tracing:
-            raise ValueError(
-                "the frontier SSSP engine's level loop is host-driven "
-                "and cannot run inside jit; call it outside jit or use "
-                "engine='dense'"
-            )
-        return frontier_bellman_ford(
-            src, dst, weights, num_nodes, sources=sources,
-            max_rounds=max_rounds, **kwargs,
+    if engine == "frontier" and tracing:
+        raise ValueError(
+            "the frontier SSSP engine's level loop is host-driven "
+            "and cannot run inside jit; call it outside jit or use "
+            "engine='dense'"
         )
-    if "min_bucket" in kwargs:
+    if engine == "dense" and "min_bucket" in kwargs:
         raise ValueError(
             "min_bucket= is a frontier-engine option; use "
             "engine='frontier' (or 'auto')"
         )
-    return bellman_ford(
-        src, dst, weights, num_nodes, sources=sources,
-        max_rounds=max_rounds, **kwargs,
-    )
+    run = frontier_bellman_ford if engine == "frontier" else bellman_ford
+    # The root span of one library call: the prep, the engine's spans,
+    # the parents pass and the host syncs of the call sit beneath it.
+    with trace.span("sssp.call", n=num_nodes) as sp:
+        if trace.enabled():
+            sp.tag(engine=engine, m2=2 * int(np.size(src)),
+                   sources=int(np.size(sources)))
+        return run(
+            src, dst, weights, num_nodes, sources=sources,
+            max_rounds=max_rounds, **kwargs,
+        )

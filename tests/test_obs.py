@@ -512,10 +512,11 @@ def test_traced_analytics_waves_time_each_stage():
 
 
 def test_every_host_sync_pragma_is_counted():
-    """Each ``disable=host-sync`` line of the CC engines has a
+    """Each ``disable=host-sync`` line of the CC and SSSP engines has a
     ``trace.count("host_sync")`` on one of the three lines above it,
     one count per pragma, so a new sync cannot land uncounted."""
-    for rel in ("src/repro/core/frontier.py", "src/repro/core/components.py"):
+    for rel in ("src/repro/core/frontier.py", "src/repro/core/components.py",
+                "src/repro/core/sssp.py"):
         with open(os.path.join(_ROOT, rel)) as f:
             lines = f.read().splitlines()
         counts = [i for i, ln in enumerate(lines)
@@ -526,3 +527,68 @@ def test_every_host_sync_pragma_is_counted():
         assert len(counts) == len(pragmas), rel
         for i in pragmas:
             assert any(i - 3 <= c < i for c in counts), f"{rel}:{i + 1}"
+
+
+def _sssp_call(**kw):
+    from repro.core import shortest_paths
+
+    r = np.random.default_rng(7)
+    src = r.integers(0, 300, 1200).astype(np.int32)
+    dst = r.integers(0, 300, 1200).astype(np.int32)
+    w = r.random(1200).astype(np.float32)
+    return shortest_paths(src, dst, w, 300, sources=5, min_bucket=64, **kw)
+
+
+def test_sssp_call_spans_and_counts():
+    """One root ``sssp.call`` per call; a live-count read per level and
+    the final one that finds the frontier empty; one ``sssp.parents``
+    post-pass; the level counters sum to ``SsspStats``."""
+    *_, stats = _sssp_call(with_stats=True)
+    _, events = _traced(lambda: _sssp_call(with_stats=True))
+    by_id = {e["args"]["span_id"]: e for e in events if e["ph"] == "X"}
+    (root,) = [e for e in events if e["name"] == "sssp.call"]
+    assert root["args"]["parent_id"] == 0
+    assert (root["args"]["engine"], root["args"]["n"], root["args"]["m2"],
+            root["args"]["sources"]) == ("frontier", 300, 2400, 1)
+    counts = root["args"]["counts"]
+    assert counts["host_sync"] == len(stats.levels) + 1
+    assert counts["sssp.relax_slots"] == stats.relax_visits
+    assert counts["sssp.mask_edges"] == stats.mask_visits
+    levels = [e for e in events if e["name"] == "sssp.level"]
+    assert len(levels) == len(stats.levels) > 1
+    for name, parent in [("sssp.prep", "sssp.call"),
+                         ("sssp.frontier", "sssp.call"),
+                         ("sssp.level", "sssp.frontier"),
+                         ("sssp.parents", "sssp.call")]:
+        spans = [e for e in events if e["name"] == name]
+        assert spans, name
+        assert {by_id[e["args"]["parent_id"]]["name"] for e in spans} == {parent}
+    assert len([e for e in events if e["name"] == "sssp.parents"]) == 1
+
+
+def test_sssp_dense_call_counts_its_reads():
+    """The dense engine reads its convergence flag, and its round count
+    where stats are asked for."""
+    from repro.core import shortest_paths
+
+    src = np.arange(9, dtype=np.int32)
+    for kw, syncs in [({}, 1), ({"with_stats": True}, 2)]:
+        _, events = _traced(lambda: shortest_paths(src, src + 1, None, 10,
+                                                   engine="dense", **kw))
+        (root,) = [e for e in events if e["name"] == "sssp.call"]
+        assert root["args"]["engine"] == "dense"
+        assert root["args"]["counts"] == {"host_sync": syncs}
+
+
+def test_sssp_untraced_call_records_nothing(monkeypatch):
+    """Tracing off: no event, and the root span's tags are never built."""
+    from repro.obs.trace import _NullSpan
+
+    trace.configure(trace="off")
+    trace.reset()
+    tagged = []
+    monkeypatch.setattr(_NullSpan, "tag",
+                        lambda self, **attrs: tagged.append(attrs) or self)
+    _sssp_call()
+    assert trace.chrome_trace()["traceEvents"] == []
+    assert not any({"engine", "m2", "sources"} & set(attrs) for attrs in tagged)

@@ -358,3 +358,64 @@ def test_serve_sssp_random_streams_property(num_requests, seed, width):
     assert len(done) == len(stream)
     for q in done:
         _assert_matches_solo(q, stream[q.uid])
+
+
+# ---------------------------------------------------------------------------
+# Graph500 kernel 3 shapes: Kronecker graphs, one root per call
+# ---------------------------------------------------------------------------
+
+
+def _kronecker(scale, seed, edgefactor=16, a=0.57, b=0.19, c=0.19):
+    """A Graph500-shaped Kronecker graph (duplicates and self-loops
+    kept, vertex ids permuted) with weights that are multiples of
+    2**-24 in [0, 1), and the vertices of degree >= 1."""
+    r = np.random.default_rng(seed)
+    m = edgefactor << scale
+    ab = a + b
+    ij = np.zeros((2, m), np.int64)
+    for level in range(scale):
+        ii = r.random(m) > ab
+        jj = r.random(m) > np.where(ii, c / (1.0 - ab), a / ab)
+        ij += np.stack([ii, jj]).astype(np.int64) << level
+    src, dst = r.permutation(1 << scale)[ij].astype(np.int32)
+    w = (r.integers(0, 1 << 24, m) * 2.0 ** -24).astype(np.float32)
+    live = np.flatnonzero(np.bincount(np.r_[src, dst], minlength=1 << scale))
+    return src, dst, w, live
+
+
+def _plain_bellman_ford(src, dst, w, n, root):
+    """Synchronous float32 Bellman-Ford over both orientations of every
+    edge until a round changes nothing, and the min-id parent rule."""
+    u, v, w2 = np.r_[src, dst], np.r_[dst, src], np.r_[w, w]
+    dist = np.full(n, np.inf, np.float32)
+    dist[root] = 0.0
+    while True:
+        nxt = dist.copy()
+        np.minimum.at(nxt, v, dist[u] + w2)
+        if np.array_equal(nxt, dist):
+            break
+        dist = nxt
+    parent = np.full(n, n, np.int64)
+    ok = (u != v) & (dist[u] + w2 == dist[v])
+    np.minimum.at(parent, v[ok], u[ok])
+    parent[(parent == n) | np.isinf(dist)] = -1
+    parent[root] = root
+    return dist, parent
+
+
+@pytest.mark.parametrize("scale,seed,pick", [
+    (8, 11, 0), (8, 11, 1), (9, 12, 2), (10, 13, 3), (10, 13, 4),
+])
+def test_kronecker_one_root_per_call_bit_exact(scale, seed, pick):
+    """The default engine, one root per call, on a Graph500-shaped
+    multigraph: distances bit for bit and parents equal to a plain
+    float32 Bellman-Ford written here."""
+    src, dst, w, live = _kronecker(scale, seed)
+    n = 1 << scale
+    root = int(np.random.default_rng(pick).choice(live))
+    assert (src == dst).any()  # self-loops kept
+    d, p, _ = shortest_paths(src, dst, w, n, sources=root)
+    want_d, want_p = _plain_bellman_ford(src, dst, w, n, root)
+    np.testing.assert_array_equal(np.asarray(d).view(np.uint32),
+                                  want_d.view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(p), want_p)

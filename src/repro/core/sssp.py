@@ -22,9 +22,15 @@ Two engines share the relax round:
   monotonically -- a relaxed-quiet edge can wake up again when its
   source's distance later drops, so each level re-compacts **from the
   full edge list** (one O(m) boolean mask gather per level, against the
-  S x bucket relax work it saves). The host sync per level is the same
-  level-synchronous design as the CC frontier engine, with
-  ``sssp.level`` spans attached at those already-paid sync points.
+  S x bucket relax work it saves). A level whose bucket is the whole
+  list (more than half the arcs live, or ``m2 <= min_bucket``) skips
+  the compaction and relaxes the list itself: the compacted buffer
+  would have the same shape, holding every live arc plus inert pads,
+  and the quiet arcs relaxed in their place are inert too (see
+  Exactness), so the level's outcome is bit-identical. The host sync
+  per level is the same level-synchronous design as the CC frontier
+  engine, with ``sssp.level`` spans attached at those already-paid
+  sync points.
 
 **Exactness.** Distances are the unique least fixpoint of the float32
 Bellman relaxations ``dist[v] = min(dist[v], dist[u] + w)`` (float add
@@ -98,6 +104,8 @@ class SsspStats:
     cost: one full-edge-list boolean gather per level to rebuild the
     frontier mask (quiet edges can wake up again -- see module
     docstring -- so compaction cannot be permanent like CC's).
+    ``dense_levels`` counts the levels whose bucket was the whole list,
+    relaxed uncompacted; they still add ``m2`` to ``relax_visits``.
     """
 
     rounds: int
@@ -105,6 +113,7 @@ class SsspStats:
     mask_visits: int  # full-list frontier-mask gathers, m2 per level
     m2: int  # oriented edge count (dense relaxes this per round)
     num_sources: int
+    dense_levels: int = 0  # levels that relaxed the whole list uncompacted
     levels: list = field(default_factory=list)  # (bucket, live) per level
 
     def publish(self, registry=None, prefix: str = "sssp.frontier") -> None:
@@ -213,11 +222,6 @@ def _edge_frontier(a, changed_nodes):
     """Edge slots whose (oriented) source node improved last round --
     the union over source rows, so one mask serves the whole batch."""
     return changed_nodes[a]
-
-
-# The weighted compaction primitive moved to core/operators.py with the
-# rest of the filter machinery; the alias keeps the engine-local name.
-_compact_weighted = compact_weighted
 
 
 @jax.jit
@@ -354,9 +358,18 @@ def frontier_bellman_ford(
         def relax(live):
             nonlocal dist, changed_nodes
             size = bucket_size(live, min_bucket=min_bucket, cap=m2)
-            with trace.span("sssp.level", bucket=size, live=live):
-                ca, cb, cw = compact_weighted(a, b, w2, fmask, size=size)
-                dist, changed_nodes = _relax_level(ca, cb, cw, dist)
+            # A bucket as long as the list holds every arc anyway, so
+            # the level relaxes the list itself: quiet arcs are inert
+            # (module docstring), and the relax program is the same
+            # shape the compacted buffer would have had.
+            dense = size == m2
+            with trace.span("sssp.level", bucket=size, live=live, dense=dense):
+                arcs = (a, b, w2) if dense else compact_weighted(
+                    a, b, w2, fmask, size=size)
+                dist, changed_nodes = _relax_level(*arcs, dist)
+            if dense:
+                stats.dense_levels += 1
+                trace.count("sssp.dense_levels")
             stats.relax_visits += size
             trace.count("sssp.relax_slots", size)
             stats.levels.append((size, live))

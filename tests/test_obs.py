@@ -532,30 +532,37 @@ def test_every_host_sync_pragma_is_counted():
 def _sssp_call(**kw):
     from repro.core import shortest_paths
 
+    # 2,000 arcs: the widest levels fill the whole list, so some levels
+    # relax it uncompacted and the others compact.
     r = np.random.default_rng(7)
-    src = r.integers(0, 300, 1200).astype(np.int32)
-    dst = r.integers(0, 300, 1200).astype(np.int32)
-    w = r.random(1200).astype(np.float32)
+    src = r.integers(0, 300, 1000).astype(np.int32)
+    dst = r.integers(0, 300, 1000).astype(np.int32)
+    w = r.random(1000).astype(np.float32)
     return shortest_paths(src, dst, w, 300, sources=5, min_bucket=64, **kw)
 
 
 def test_sssp_call_spans_and_counts():
     """One root ``sssp.call`` per call; a live-count read per level and
     the final one that finds the frontier empty; one ``sssp.parents``
-    post-pass; the level counters sum to ``SsspStats``."""
+    post-pass; the level counters sum to ``SsspStats``, and each level
+    says whether its bucket was the whole list."""
     *_, stats = _sssp_call(with_stats=True)
     _, events = _traced(lambda: _sssp_call(with_stats=True))
     by_id = {e["args"]["span_id"]: e for e in events if e["ph"] == "X"}
     (root,) = [e for e in events if e["name"] == "sssp.call"]
     assert root["args"]["parent_id"] == 0
     assert (root["args"]["engine"], root["args"]["n"], root["args"]["m2"],
-            root["args"]["sources"]) == ("frontier", 300, 2400, 1)
+            root["args"]["sources"]) == ("frontier", 300, 2000, 1)
     counts = root["args"]["counts"]
     assert counts["host_sync"] == len(stats.levels) + 1
     assert counts["sssp.relax_slots"] == stats.relax_visits
     assert counts["sssp.mask_edges"] == stats.mask_visits
+    assert counts["sssp.dense_levels"] == stats.dense_levels
     levels = [e for e in events if e["name"] == "sssp.level"]
     assert len(levels) == len(stats.levels) > 1
+    dense = [e["args"]["dense"] for e in levels]
+    assert dense == [e["args"]["bucket"] == 2000 for e in levels]
+    assert 0 < sum(dense) == stats.dense_levels < len(dense)
     for name, parent in [("sssp.prep", "sssp.call"),
                          ("sssp.frontier", "sssp.call"),
                          ("sssp.level", "sssp.frontier"),

@@ -256,6 +256,52 @@ def test_frontier_stats_beat_dense_on_chains():
     assert fstats.num_sources == dstats.num_sources == 1
 
 
+def _random_graph(n, m, seed):
+    r = np.random.default_rng(seed)
+    edges = r.integers(0, n, size=(m, 2)).astype(np.int32)
+    return n, edges, r.random(m).astype(np.float32)
+
+
+def _chain_graph(n):
+    from repro.ops.kiss import list_graph
+
+    edges = list_graph(n, 1, seed=22)
+    return n, edges, _eighth_weights(edges)
+
+
+@pytest.mark.parametrize("graph,min_bucket,dense", [
+    (lambda: _chain_graph(256), 16, "none"),
+    (lambda: _random_graph(300, 1000, 7), 64, "some"),
+    (lambda: _random_graph(300, 1000, 7), 2048, "every"),
+    (lambda: _random_graph(40, 100, 3), 1024, "every"),  # m2 < min_bucket
+], ids=["chain-none", "random-some", "random-bucket-is-list",
+        "small-m2-under-min-bucket"])
+def test_dense_levels_relax_the_whole_list_exactly(graph, min_bucket, dense):
+    """A level whose bucket is the whole arc list relaxes the list
+    uncompacted: distances and parents stay bit-exact vs the dense walk
+    and both serial oracles, every such level is counted, and the visit
+    counters still read a bucket per level and a mask per read."""
+    n, edges, w = graph()
+    src, dst = edges[:, 0], edges[:, 1]
+    fd, fp, _, stats = frontier_bellman_ford(
+        src, dst, w, n, sources=5, min_bucket=min_bucket, with_stats=True
+    )
+    dd, dp, _ = bellman_ford(src, dst, w, n, sources=5)
+    for want_d, want_p in [(np.asarray(dd), np.asarray(dp)),
+                           serial_bellman_ford(edges, w, n, 5),
+                           serial_dijkstra(edges, w, n, 5)]:
+        np.testing.assert_array_equal(np.asarray(fd).view(np.uint32),
+                                      np.asarray(want_d).view(np.uint32))
+        np.testing.assert_array_equal(np.asarray(fp), want_p)
+    sizes = [size for size, _ in stats.levels]
+    hits = sum(size == stats.m2 for size in sizes)
+    assert stats.dense_levels == hits
+    assert {"none": hits == 0, "some": 0 < hits < len(sizes),
+            "every": hits == len(sizes) > 0}[dense], stats.levels
+    assert stats.relax_visits == sum(sizes)
+    assert stats.mask_visits == stats.m2 * (len(sizes) + 1)
+
+
 # ---------------------------------------------------------------- serve
 
 

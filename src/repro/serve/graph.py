@@ -11,19 +11,21 @@ serving:
 * requests queue up and are admitted in FIFO order into WAVES under a
   node/edge budget (``serve/waves.WaveScheduler``, the same outer loop
   as the LM token engine);
-* each wave is packed into ONE disjoint-union graph by node/edge offset
-  packing -- request i's nodes become ``[node_off[i], node_off[i] +
-  n_i)`` -- then padded to a power-of-two **capacity bucket**
-  (``core/frontier.next_pow2`` on nodes and edges; pad nodes are
-  isolated, pad edges are inert (0, 0) self-loops, and the analytics
-  stage pads its forest-edge buffer to the node capacity so the tour
-  ranks at the fixed ``2 * node_cap`` arc capacity of
-  ``trees/tour.tour_capacity``'s convention);
-* the packed union runs through the existing engines as one batched
-  device program per wave stage -- ``connected_components`` /
+* every wave, whatever its family, goes through ONE packer
+  (``_run_wave``): node/edge offset packing into a disjoint-union
+  graph -- request i's nodes become ``[node_off[i], node_off[i] +
+  n_i)`` -- padded to a power-of-two **capacity bucket**
+  (``core/operators.next_pow2`` on nodes and edges; pad nodes are
+  isolated, pad edges are inert (0, 0) self-loops);
+* only three things differ by family. The pad arrays: sssp adds
+  ``+inf`` pad weights and source rows, pagerank ``0.0`` pad weights
+  and a teleport vector. The engine call: ``connected_components`` /
   ``spanning_forest`` / ``tree_analytics`` with ``dedup=False`` so
-  shapes stay bucket-static -- and results are unpacked per request by
-  offset.
+  shapes stay bucket-static (the analytics stage pads its forest-edge
+  buffer to the node capacity so the tour ranks at the fixed
+  ``2 * node_cap`` arc capacity of ``trees/tour.tour_capacity``'s
+  convention), ``shortest_paths`` or ``pagerank``. And the unpack,
+  which slices each request's results back out by offset.
 
 **Bit-exactness.** CC, spanning forests, and Euler-tour analytics over
 a disjoint union decompose per component: every SV hook compares labels
@@ -58,26 +60,25 @@ import jax
 import numpy as np
 
 from repro.core.components import check_choice
-from repro.core.frontier import next_pow2
+from repro.core.operators import next_pow2
 from repro.obs import trace
 from repro.serve.waves import WaveScheduler
 
-# Request kinds. The first three form a pipeline-stage chain -- each
-# stage subsumes the ones before it, so a mixed wave runs the deepest
-# stage any member needs (record_hooks and the tour stages are
-# label-neutral by construction). "sssp" and "pagerank" are OUTSIDE
-# the chain: each runs a different device program (relax-min over
-# weighted edges; add-monoid mass push), so ``_next_wave`` packs them
-# only with their own kind -- stage promotion never mixes families.
+# Request kinds, in stage order. The first three form the cc-chain
+# family, a pipeline-stage chain -- each stage subsumes the ones before
+# it, so a mixed wave runs the deepest stage any member needs
+# (record_hooks and the tour stages are label-neutral by construction).
+# The rest are each a wave-packing family of their own: each runs a
+# different device program (relax-min over weighted edges; add-monoid
+# mass push), so ``_next_wave`` packs them only with their own kind --
+# stage promotion never mixes families, and a family-pure wave's
+# deepest ``_STAGE`` is its stage.
 KINDS = ("cc", "forest", "analytics", "sssp", "pagerank")
-_STAGE = {
-    k: i for i, k in enumerate(KINDS) if k not in ("sssp", "pagerank")
+_STAGE = {k: i for i, k in enumerate(KINDS)}
+_FAMILY = {
+    k: "cc-chain" if i <= _STAGE["analytics"] else k
+    for k, i in _STAGE.items()
 }
-
-
-def _family(kind: str) -> str:
-    """Wave-packing family: kinds that can share one device program."""
-    return kind if kind in ("sssp", "pagerank") else "cc-chain"
 
 
 @dataclass
@@ -171,7 +172,7 @@ class GraphServeEngine(WaveScheduler):
     * ``max_sources`` (8) -- per-request source budget for
       ``kind="sssp"`` requests; a wave's source rows pack into a
       ``src_cap`` power-of-two bucket dimension (see
-      ``_run_sssp_wave``). sssp waves map ``engine="auto"`` to
+      ``_call_sssp``). sssp waves map ``engine="auto"`` to
       ``"dense"`` like CC waves and reject ``mesh=`` /
       ``engine="sharded_frontier"`` at submit.
     * ``damping`` (0.85) / ``pagerank_iters`` (None =
@@ -181,7 +182,7 @@ class GraphServeEngine(WaveScheduler):
       ``pagerank_iters`` iterations: a tolerance-driven stop would
       run every wave to its slowest member's iteration count, making
       a request's scores depend on its wave-mates. Fixed iterations
-      keep batched == solo bit-exact (see ``_run_pagerank_wave``).
+      keep batched == solo bit-exact (see ``_call_pagerank``).
     * ``engine=`` / ``rank_engine=`` / ``kernel_impl=`` /
       ``num_splitters=`` / ``mesh=`` and any extra engine kwargs
       (``hook_impl=``, ``exchange=``, ``min_bucket=``, ...) dispatch
@@ -270,7 +271,7 @@ class GraphServeEngine(WaveScheduler):
         self.mesh = mesh
         self.engine_kwargs = dict(engine_kwargs)
         self.wave_records: list[WaveRecord] = []
-        self._buckets: set[tuple[str, int, int]] = set()
+        self._buckets: set[tuple] = set()  # sssp keys add src_cap
 
     # -- deterministic counters (guarded by benchmarks/run.py --check) --
     @property
@@ -327,10 +328,8 @@ class GraphServeEngine(WaveScheduler):
                 f"request {req.uid}: edge endpoints outside "
                 f"[0, {req.num_nodes})"
             )
-        if req.kind == "sssp":
-            self._validate_sssp(req)
-        elif req.kind == "pagerank":
-            self._validate_pagerank(req)
+        if _FAMILY[req.kind] != "cc-chain":
+            self._validate_weighted(req)
         elif req.weights is not None or req.sources is not None:
             raise ValueError(
                 f"request {req.uid}: weights/sources are only consumed "
@@ -338,21 +337,35 @@ class GraphServeEngine(WaveScheduler):
             )
         super().submit(req)
 
-    def _validate_sssp(self, req: GraphRequest) -> None:
-        """Normalize + validate the sssp-only request fields, loudly."""
+    def _validate_weighted(self, req: GraphRequest) -> None:
+        """Normalize + validate an sssp / pagerank request, loudly. Both
+        run a single-device engine and take float32 ``weights`` (None =
+        unit), finite and >= 0. sssp also takes ``sources`` (None =
+        ``[0]``) and carries ``min_bucket=`` over; pagerank takes
+        neither (its knobs are the ctor's damping= / pagerank_iters=)."""
+        sssp = req.kind == "sssp"
         if self.mesh is not None or self.engine == "sharded_frontier":
             raise ValueError(
-                f"request {req.uid}: sssp waves run the single-device "
-                "relax engines; drop mesh= / engine='sharded_frontier'"
+                f"request {req.uid}: {req.kind} waves run the single-"
+                "device " + ("relax engines" if sssp else "dense engine")
+                + "; drop mesh= / engine='sharded_frontier'"
             )
-        extra = set(self.engine_kwargs) - {"min_bucket"}
+        knobs = {"min_bucket"} if sssp else set()
+        extra = sorted(set(self.engine_kwargs) - knobs)
         if extra:
             raise ValueError(
-                f"request {req.uid}: {sorted(extra)} are not sssp "
-                "engine knobs (only min_bucket= carries over)"
+                f"request {req.uid}: {extra} are not {req.kind} engine "
+                "knobs (" + ("only min_bucket= carries over" if sssp else
+                "the dense fixed-iteration engine takes only damping= / "
+                "pagerank_iters=") + ")"
+            )
+        if not sssp and req.sources is not None:
+            raise ValueError(
+                f"request {req.uid}: sources is an sssp-only field "
+                "(pagerank scores every node)"
             )
         if req.weights is None:
-            w = np.ones(req.num_edges, np.float32)  # unit weights: BFS
+            w = np.ones(req.num_edges, np.float32)  # unit (sssp: BFS)
         else:
             w = np.asarray(req.weights, np.float32).ravel()
         if w.shape != req.src.shape:
@@ -362,9 +375,12 @@ class GraphServeEngine(WaveScheduler):
             )
         if req.num_edges and (not np.isfinite(w).all() or bool((w < 0).any())):
             raise ValueError(
-                f"request {req.uid}: sssp weights must be finite and >= 0"
+                f"request {req.uid}: {req.kind} weights must be finite "
+                "and >= 0"
             )
         req.weights = w
+        if not sssp:
+            return
         if req.sources is None:
             s = np.zeros(1, np.int32)
         else:
@@ -380,45 +396,10 @@ class GraphServeEngine(WaveScheduler):
             )
         req.sources = s
 
-    def _validate_pagerank(self, req: GraphRequest) -> None:
-        """Normalize + validate the pagerank-only request fields."""
-        if self.mesh is not None or self.engine == "sharded_frontier":
-            raise ValueError(
-                f"request {req.uid}: pagerank waves run the single-"
-                "device dense engine; drop mesh= / "
-                "engine='sharded_frontier'"
-            )
-        if self.engine_kwargs:
-            raise ValueError(
-                f"request {req.uid}: {sorted(self.engine_kwargs)} are "
-                "not pagerank engine knobs (the dense fixed-iteration "
-                "engine takes only damping= / pagerank_iters=)"
-            )
-        if req.sources is not None:
-            raise ValueError(
-                f"request {req.uid}: sources is an sssp-only field "
-                "(pagerank scores every node)"
-            )
-        if req.weights is None:
-            w = np.ones(req.num_edges, np.float32)  # unit weights
-        else:
-            w = np.asarray(req.weights, np.float32).ravel()
-        if w.shape != req.src.shape:
-            raise ValueError(
-                f"request {req.uid}: weights length {w.shape} != edge "
-                f"count {req.src.shape}"
-            )
-        if req.num_edges and (not np.isfinite(w).all() or bool((w < 0).any())):
-            raise ValueError(
-                f"request {req.uid}: pagerank weights must be finite "
-                "and >= 0"
-            )
-        req.weights = w
-
     def _next_wave(self) -> list[GraphRequest]:
         """FIFO greedy packing under the node/edge budget (the
         degradation caps, when an OOM has lowered them). A wave stays
-        within one packing FAMILY (cc-chain vs sssp): the families run
+        within one packing FAMILY (cc-chain, sssp, pagerank): they run
         different device programs, so mixing them would force both
         into one wave's single batched call. FIFO order is preserved
         inside the wave; a family boundary closes the wave (no
@@ -427,7 +408,7 @@ class GraphServeEngine(WaveScheduler):
         nodes = edges = 0
         while self.queue and len(wave) < self.max_requests:
             r = self.queue[0]
-            if wave and _family(r.kind) != _family(wave[0].kind):
+            if wave and _FAMILY[r.kind] != _FAMILY[wave[0].kind]:
                 break
             if wave and (
                 nodes + r.num_nodes > self._node_budget
@@ -440,7 +421,8 @@ class GraphServeEngine(WaveScheduler):
         return wave
 
     def _wave_caps(self, wave: list[GraphRequest]) -> tuple[int, int]:
-        """The capacity bucket a wave maps to (same math as _run_wave)."""
+        """The capacity bucket a wave maps to: the one place the
+        packer's ``node_cap`` / ``edge_cap`` are computed."""
         n_union = sum(r.num_nodes for r in wave)
         m_union = sum(r.num_edges for r in wave)
         node_cap = max(self.min_nodes, next_pow2(n_union))
@@ -486,94 +468,85 @@ class GraphServeEngine(WaveScheduler):
         return subs
 
     def _run_wave(self, wave: list[GraphRequest]):
-        from repro.core import connected_components
-        from repro.trees import spanning_forest, tree_analytics
-
+        """Serve one wave (family-pure by ``_next_wave``) as one padded
+        disjoint-union engine call. Every step is shared but the
+        family's pad arrays, its engine call with readback
+        (``_call_cc_chain`` / ``_call_sssp`` / ``_call_pagerank``) and
+        its unpack."""
         if self.fault_plan is not None:
             self.fault_plan.check_wave(wave)
-
-        if wave[0].kind == "sssp":  # family-pure by _next_wave
-            return self._run_sssp_wave(wave)
-        if wave[0].kind == "pagerank":
-            return self._run_pagerank_wave(wave)
-
         stage = KINDS[max(_STAGE[r.kind] for r in wave)]
-        node_off = np.cumsum([0] + [r.num_nodes for r in wave])
-        n_union = int(node_off[-1])
-        m_union = sum(r.num_edges for r in wave)
-        node_cap = max(self.min_nodes, next_pow2(n_union))
-        edge_cap = max(self.min_edges, next_pow2(max(m_union, 1)))
+        node_cap, edge_cap = self._wave_caps(wave)
+        caps = {"node_cap": node_cap, "edge_cap": edge_cap}
+        if stage == "sssp":  # source rows: a power-of-two bucket dim too
+            caps["src_cap"] = next_pow2(sum(len(r.sources) for r in wave))
         if self.fault_plan is not None:
             self.fault_plan.check_bucket(node_cap)
+        node_off = np.cumsum([0] + [r.num_nodes for r in wave])
+        n_union = int(node_off[-1])
         with trace.span(
-            "serve.wave.pack", requests=len(wave), stage=stage,
-            node_cap=node_cap, edge_cap=edge_cap,
+            "serve.wave.pack", requests=len(wave), stage=stage, **caps
         ):
-            src = np.zeros((edge_cap,), np.int32)  # pad: inert self-loops
+            src = np.zeros((edge_cap,), np.int32)  # pad: (0, 0) self-loops
             dst = np.zeros((edge_cap,), np.int32)
-            eo = 0
+            wts = aux = None
+            if stage == "sssp":  # pad edges at +inf: inert, never parents
+                wts = np.full((edge_cap,), np.inf, np.float32)
+                # pad source rows target a pad node when one exists (an
+                # isolated node: the row converges immediately)
+                aux = np.full(
+                    (caps["src_cap"],), n_union if n_union < node_cap else 0,
+                    np.int32,
+                )
+                ro = 0
+                for r, o in zip(wave, node_off):
+                    aux[ro:ro + len(r.sources)] = r.sources + o
+                    ro += len(r.sources)
+            elif stage == "pagerank":  # pad edges of weight 0: no mass
+                wts = np.zeros((edge_cap,), np.float32)
+                aux = np.zeros((node_cap,), np.float32)  # pad teleport: 0
+                for r, o in zip(wave, node_off):
+                    aux[o:o + r.num_nodes] = np.full(
+                        r.num_nodes, 1.0 / r.num_nodes, np.float32
+                    )
+            m_union = 0
             for r, o in zip(wave, node_off):
-                src[eo:eo + r.num_edges] = r.src + o
-                dst[eo:eo + r.num_edges] = r.dst + o
-                eo += r.num_edges
+                src[m_union:m_union + r.num_edges] = r.src + o
+                dst[m_union:m_union + r.num_edges] = r.dst + o
+                if wts is not None:
+                    wts[m_union:m_union + r.num_edges] = r.weights
+                m_union += r.num_edges
 
-        bucket = (stage, node_cap, edge_cap)
+        bucket = (stage, *caps.values())
         new_bucket = bucket not in self._buckets
-
-        kw = dict(
-            self.engine_kwargs, engine=self.engine, mesh=self.mesh,
-            dedup=False,
-        )
+        if stage == "sssp":
+            # "auto" resolves to "dense" for the same reason as CC
+            # serving (module docstring); a pinned "frontier" is honoured
+            engine = "frontier" if self.engine == "frontier" else "dense"
+            call, unpack = self._call_sssp, self._unpack_sssp
+            etag = {"engine": engine}
+        elif stage == "pagerank":
+            call, unpack = self._call_pagerank, self._unpack_pagerank
+            etag = {"engine": "dense"}
+        else:
+            call, unpack, etag = self._call_cc_chain, self._unpack, {}
+        kw = dict(etag)
         if self.fault_plan is not None and self.fault_plan.wants_nonconverge(
             wave
         ):
-            # Remove the round budget so the core engines' REAL
+            # Remove the round budget so the engine's REAL
             # ConvergenceError sentinel fires for this wave.
             kw["max_rounds"] = 0
-        # The engine span covers the batched device program AND the
-        # readback (its own ``serve.wave.readback`` span) -- those reads
-        # are the wave's existing host sync, so the span closes on an
-        # already-synced boundary (no block_on needed).
         with trace.span(
-            "serve.wave.engine", stage=stage, requests=len(wave),
-            node_cap=node_cap, edge_cap=edge_cap, new_bucket=new_bucket,
+            "serve.wave.engine", stage=stage, requests=len(wave), **caps,
+            new_bucket=new_bucket, **etag,
         ) as esp:
-            extras = None
-            if stage == "cc":
-                labels, rounds = connected_components(
-                    src, dst, node_cap, **kw
-                )
-                edge_u = edge_v = None
-            elif stage == "forest":
-                forest = spanning_forest(src, dst, node_cap, **kw)
-                labels, rounds = forest.labels, forest.rounds
-                edge_u, edge_v = forest.edge_u, forest.edge_v
-            else:
-                ta = tree_analytics(
-                    src, dst, node_cap,
-                    rank_engine=self.rank_engine,
-                    kernel_impl=self.kernel_impl,
-                    num_splitters=self.num_splitters,
-                    pad_edges_to=node_cap,
-                    **kw,
-                )
-                labels, rounds = ta.forest.labels, ta.forest.rounds
-                edge_u, edge_v = ta.forest.edge_u, ta.forest.edge_v
-                extras = (
-                    ta.parent, ta.depth, ta.subtree_size,
-                    ta.computations.preorder, ta.computations.postorder,
-                )
-            with trace.span("serve.wave.readback", stage=stage):
-                if stage == "cc":  # the forest stages read these already
-                    trace.count("host_sync", 2)
-                    labels, rounds = np.asarray(labels), int(rounds)
-                if extras is not None:
-                    trace.count("host_sync", len(extras))
-                    extras = tuple(np.asarray(x) for x in extras)
+            edges = (src, dst) if wts is None else (src, dst, wts)
+            rounds, out = call(stage, edges, aux, node_cap, kw)
             esp.tag(rounds=rounds)
 
         with trace.span("serve.wave.unpack", requests=len(wave)):
-            self._unpack(wave, node_off, labels, edge_u, edge_v, extras)
+            unpack(wave, node_off, *out)
 
         # Bucket accounting only for waves that ran to completion: a
         # wave that failed above (injected fault, OOM, engine error)
@@ -584,186 +557,112 @@ class GraphServeEngine(WaveScheduler):
             num_nodes=n_union, num_edges=m_union,
             node_cap=node_cap, edge_cap=edge_cap,
             new_bucket=new_bucket, rounds=rounds,
+            src_cap=caps.get("src_cap", 0),
         )
         self.wave_records.append(rec)
         rec.publish(self.metrics)
 
-    def _run_sssp_wave(self, wave: list[GraphRequest]):
-        """The sssp-family wave: one batched multi-source
-        ``shortest_paths`` call over the disjoint union. Every
-        request's sources become rows of the packed distance array
-        (offset-shifted), padded to a ``src_cap`` power-of-two row
-        count; pad edges are +inf-weight self-loops (inert under
-        relax-min, never parents) and pad source rows target a pad
-        node when one exists (an isolated node: the row converges
-        immediately). Disjoint union ⇒ request i's rows are its solo
-        rows bit-exactly: no finite-weight path crosses an offset
-        boundary, so other requests' columns stay +inf / -1 and are
-        sliced away at unpack. ``fault_plan.check_wave`` already ran
-        in ``_run_wave``."""
+    def _call_cc_chain(self, stage, edges, aux, node_cap, kw):
+        """The cc-chain engine call for ``stage``, then its readback.
+        The readback's reads are the wave's host sync, so the enclosing
+        ``serve.wave.engine`` span closes on an already-synced boundary
+        (no block_on needed)."""
+        from repro.core import connected_components
+        from repro.trees import spanning_forest, tree_analytics
+
+        kw = dict(
+            self.engine_kwargs, engine=self.engine, mesh=self.mesh,
+            dedup=False, **kw,
+        )
+        edge_u = edge_v = extras = None
+        if stage == "cc":
+            labels, rounds = connected_components(*edges, node_cap, **kw)
+        elif stage == "forest":
+            forest = spanning_forest(*edges, node_cap, **kw)
+            labels, rounds = forest.labels, forest.rounds
+            edge_u, edge_v = forest.edge_u, forest.edge_v
+        else:
+            ta = tree_analytics(
+                *edges, node_cap,
+                rank_engine=self.rank_engine,
+                kernel_impl=self.kernel_impl,
+                num_splitters=self.num_splitters,
+                pad_edges_to=node_cap,
+                **kw,
+            )
+            labels, rounds = ta.forest.labels, ta.forest.rounds
+            edge_u, edge_v = ta.forest.edge_u, ta.forest.edge_v
+            extras = (
+                ta.parent, ta.depth, ta.subtree_size,
+                ta.computations.preorder, ta.computations.postorder,
+            )
+        with trace.span("serve.wave.readback", stage=stage):
+            if stage == "cc":  # the forest stages read these already
+                trace.count("host_sync", 2)
+                labels, rounds = np.asarray(labels), int(rounds)
+            if extras is not None:
+                trace.count("host_sync", len(extras))
+                extras = tuple(np.asarray(x) for x in extras)
+        return rounds, (labels, edge_u, edge_v, extras)
+
+    def _call_sssp(self, stage, edges, srcs, node_cap, kw):
+        """One batched multi-source ``shortest_paths`` call over the
+        disjoint union. Every request's sources are offset-shifted rows
+        of the packed distance array, padded to ``src_cap`` rows. Pad
+        edges are +inf-weight self-loops (inert under relax-min, never
+        parents). No finite-weight path crosses an offset boundary, so
+        request i's rows are its solo rows bit-exactly: other requests'
+        columns stay +inf / -1 and are sliced away at unpack."""
         from repro.core import shortest_paths
 
-        stage = "sssp"
-        node_off = np.cumsum([0] + [r.num_nodes for r in wave])
-        n_union = int(node_off[-1])
-        m_union = sum(r.num_edges for r in wave)
-        node_cap = max(self.min_nodes, next_pow2(n_union))
-        edge_cap = max(self.min_edges, next_pow2(max(m_union, 1)))
-        row_off = np.cumsum([0] + [len(r.sources) for r in wave])
-        src_cap = next_pow2(int(row_off[-1]))
-        if self.fault_plan is not None:
-            self.fault_plan.check_bucket(node_cap)
-        with trace.span(
-            "serve.wave.pack", requests=len(wave), stage=stage,
-            node_cap=node_cap, edge_cap=edge_cap, src_cap=src_cap,
-        ):
-            src = np.zeros((edge_cap,), np.int32)  # pad: self-loops...
-            dst = np.zeros((edge_cap,), np.int32)
-            wts = np.full((edge_cap,), np.inf, np.float32)  # ...at +inf
-            pad_src = n_union if n_union < node_cap else 0
-            srcs = np.full((src_cap,), pad_src, np.int32)
-            eo = 0
-            for r, o, ro in zip(wave, node_off, row_off):
-                src[eo:eo + r.num_edges] = r.src + o
-                dst[eo:eo + r.num_edges] = r.dst + o
-                wts[eo:eo + r.num_edges] = r.weights
-                eo += r.num_edges
-                srcs[ro:ro + len(r.sources)] = r.sources + o
-
-        bucket = (stage, node_cap, edge_cap, src_cap)
-        new_bucket = bucket not in self._buckets
-
-        # "auto" resolves to "dense" for the same reason as CC serving:
-        # the frontier ladder's data-dependent inner buckets would break
-        # the wave's compile-count guarantee. A pinned "frontier" is
-        # honoured (bit-exact; bounded ladder compiles per bucket).
-        engine = "frontier" if self.engine == "frontier" else "dense"
-        kw = dict(self.engine_kwargs)  # only min_bucket= survives submit
-        if engine != "frontier":
-            kw.pop("min_bucket", None)
-        if self.fault_plan is not None and self.fault_plan.wants_nonconverge(
-            wave
-        ):
-            kw["max_rounds"] = 0  # fire the REAL relax-bound sentinel
-        with trace.span(
-            "serve.wave.engine", stage=stage, requests=len(wave),
-            node_cap=node_cap, edge_cap=edge_cap, src_cap=src_cap,
-            new_bucket=new_bucket, engine=engine,
-        ) as esp:
-            dist, pred, rounds = shortest_paths(
-                src, dst, wts, node_cap, sources=srcs, engine=engine, **kw
-            )
-            dist = np.asarray(dist)
-            pred = np.asarray(pred)
-            esp.tag(rounds=int(rounds))
-
-        with trace.span("serve.wave.unpack", requests=len(wave)):
-            for r, o, ro in zip(wave, node_off, row_off):
-                hi = o + r.num_nodes
-                p = pred[ro:ro + len(r.sources), o:hi]
-                r.result = GraphResult(
-                    dist=dist[ro:ro + len(r.sources), o:hi],
-                    # unreachable stays -1; reachable parents shift back
-                    pred=np.where(p >= 0, p - o, -1).astype(np.int32),
-                    sources=r.sources.copy(),
-                )
-                r.done = True
-
-        self._buckets.add(bucket)
-        rec = WaveRecord(
-            requests=len(wave), stage=stage,
-            num_nodes=n_union, num_edges=m_union,
-            node_cap=node_cap, edge_cap=edge_cap,
-            new_bucket=new_bucket, rounds=int(rounds), src_cap=src_cap,
+        if kw["engine"] == "frontier":  # only min_bucket= survives submit
+            kw = dict(self.engine_kwargs, **kw)
+        dist, pred, rounds = shortest_paths(
+            *edges, node_cap, sources=srcs, **kw
         )
-        self.wave_records.append(rec)
-        rec.publish(self.metrics)
+        return int(rounds), (np.asarray(dist), np.asarray(pred))
 
-    def _run_pagerank_wave(self, wave: list[GraphRequest]):
-        """The pagerank-family wave: one dense fixed-iteration
-        ``pagerank`` call over the disjoint union. Each request keeps
-        its SOLO teleport vector in its node slice (``1/n_i`` uniform
-        mass -- the same float64-literal rounding the solo default
-        uses), pad nodes get teleport 0, and pad edges are
-        weight-0.0 self-loops: they push zero mass and add zero
-        degree, and ``x + 0.0f == x`` bitwise for the non-negative
-        scores/degrees PageRank produces. Mass never crosses an
-        offset boundary in a disjoint union and the packed edge-slot
-        order restricted to one request is its solo order (forward
-        arcs then backward arcs, pads between them contributing
-        +0.0), so the deterministic scatter-add accumulates each
-        node's mass in exactly its solo sequence: every unpacked
-        ``scores`` slice is bit-identical to the solo dense run at
-        ``pagerank_iters`` iterations (asserted in
-        ``tests/test_serve_graph.py``). ``fault_plan.check_wave``
-        already ran in ``_run_wave``."""
+    def _call_pagerank(self, stage, edges, teleport, node_cap, kw):
+        """One dense fixed-iteration ``pagerank`` call over the disjoint
+        union. Each request keeps its SOLO teleport vector in its node
+        slice (``1/n_i`` uniform mass -- the same float64-literal
+        rounding the solo default uses), pad nodes get teleport 0, and
+        pad edges are weight-0.0 self-loops: they push zero mass and add
+        zero degree, and ``x + 0.0f == x`` bitwise for the non-negative
+        scores/degrees PageRank produces. Mass never crosses an offset
+        boundary in a disjoint union and the packed edge-slot order
+        restricted to one request is its solo order (forward arcs then
+        backward arcs, pads between them contributing +0.0), so the
+        deterministic scatter-add accumulates each node's mass in
+        exactly its solo sequence: every unpacked ``scores`` slice is
+        bit-identical to the solo dense run at ``pagerank_iters``
+        iterations (asserted in ``tests/test_serve_graph.py``)."""
         from repro.core.pagerank import pagerank
 
-        stage = "pagerank"
-        node_off = np.cumsum([0] + [r.num_nodes for r in wave])
-        n_union = int(node_off[-1])
-        m_union = sum(r.num_edges for r in wave)
-        node_cap = max(self.min_nodes, next_pow2(n_union))
-        edge_cap = max(self.min_edges, next_pow2(max(m_union, 1)))
-        if self.fault_plan is not None:
-            self.fault_plan.check_bucket(node_cap)
-        with trace.span(
-            "serve.wave.pack", requests=len(wave), stage=stage,
-            node_cap=node_cap, edge_cap=edge_cap,
-        ):
-            src = np.zeros((edge_cap,), np.int32)  # pad: self-loops...
-            dst = np.zeros((edge_cap,), np.int32)
-            wts = np.zeros((edge_cap,), np.float32)  # ...of weight 0
-            tel = np.zeros((node_cap,), np.float32)
-            eo = 0
-            for r, o in zip(wave, node_off):
-                src[eo:eo + r.num_edges] = r.src + o
-                dst[eo:eo + r.num_edges] = r.dst + o
-                wts[eo:eo + r.num_edges] = r.weights
-                eo += r.num_edges
-                tel[o:o + r.num_nodes] = np.full(
-                    r.num_nodes, 1.0 / r.num_nodes, np.float32
-                )
-
-        bucket = (stage, node_cap, edge_cap)
-        new_bucket = bucket not in self._buckets
-
-        kw = {}
-        if self.fault_plan is not None and self.fault_plan.wants_nonconverge(
-            wave
-        ):
-            # Cap the iteration budget below the fixed count so the
-            # dense engine's REAL ConvergenceError sentinel fires.
-            kw["max_rounds"] = 0
-        with trace.span(
-            "serve.wave.engine", stage=stage, requests=len(wave),
-            node_cap=node_cap, edge_cap=edge_cap, new_bucket=new_bucket,
-            engine="dense",
-        ) as esp:
-            scores, iters = pagerank(
-                src, dst, wts, node_cap,
-                damping=self.damping, teleport=tel,
-                num_iters=self.pagerank_iters, engine="dense", **kw,
-            )
-            scores = np.asarray(scores)
-            esp.tag(rounds=int(iters))
-
-        with trace.span("serve.wave.unpack", requests=len(wave)):
-            for r, o in zip(wave, node_off):
-                r.result = GraphResult(
-                    scores=scores[o:o + r.num_nodes].copy()
-                )
-                r.done = True
-
-        self._buckets.add(bucket)
-        rec = WaveRecord(
-            requests=len(wave), stage=stage,
-            num_nodes=n_union, num_edges=m_union,
-            node_cap=node_cap, edge_cap=edge_cap,
-            new_bucket=new_bucket, rounds=int(iters),
+        scores, iters = pagerank(
+            *edges, node_cap, damping=self.damping, teleport=teleport,
+            num_iters=self.pagerank_iters, **kw,
         )
-        self.wave_records.append(rec)
-        rec.publish(self.metrics)
+        return int(iters), (np.asarray(scores),)
+
+    def _unpack_sssp(self, wave, node_off, dist, pred):
+        ro = 0
+        for r, o in zip(wave, node_off):
+            rows, hi = slice(ro, ro + len(r.sources)), o + r.num_nodes
+            p = pred[rows, o:hi]
+            r.result = GraphResult(
+                dist=dist[rows, o:hi],
+                # unreachable stays -1; reachable parents shift back
+                pred=np.where(p >= 0, p - o, -1).astype(np.int32),
+                sources=r.sources.copy(),
+            )
+            r.done = True
+            ro += len(r.sources)
+
+    def _unpack_pagerank(self, wave, node_off, scores):
+        for r, o in zip(wave, node_off):
+            r.result = GraphResult(scores=scores[o:o + r.num_nodes].copy())
+            r.done = True
 
     def _unpack(self, wave, node_off, labels, edge_u, edge_v, extras):
         """Slice the packed union's outputs back to request-local ids."""

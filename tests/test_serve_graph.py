@@ -244,7 +244,7 @@ def test_pagerank_batched_bit_exact_vs_solo_and_oracle():
 
 
 def test_three_way_family_boundary_fifo_stable():
-    """The _family packing boundary over all three families: a wave
+    """The _FAMILY packing boundary over all three families: a wave
     closes AT the boundary in FIFO order (no reordering past it --
     later same-family requests are NOT pulled forward), each wave is
     family-pure, and stage promotion never crosses a family."""
